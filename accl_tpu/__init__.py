@@ -8,10 +8,6 @@ eager/rendezvous two-sided protocol becomes a tag-matched send/recv engine on
 top of single-pair ``ppermute`` moves. See SURVEY.md for the design map.
 """
 
-# Version-bridging first: compat aliases renamed jax/pallas APIs into
-# their current spellings before any kernel module loads.
-from . import compat as _compat  # noqa: F401
-
 # Under the per-rank launcher (accl_tpu.launch — the mpirun analog), join
 # the multi-controller runtime before any JAX backend use.
 from . import multiproc as _multiproc

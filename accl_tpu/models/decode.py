@@ -63,7 +63,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import shard_map
+from jax import shard_map
 from .. import device_api as dapi
 from ..constants import dataType
 from .mlp import TP_AXIS

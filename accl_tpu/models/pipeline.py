@@ -1024,7 +1024,7 @@ def build_pp_transformer_train_step(mesh, d_model: int, d_hidden: int,
     The returned step carries ``.schedule``, ``.decision_source``,
     ``.fused``, ``.engage_reason``, ``.table``, ``.stash_slots``."""
     from jax.sharding import PartitionSpec as P
-    from ..compat import shard_map
+    from jax import shard_map
     from ..ops import collective_matmul as cm
     from ..ops import pipeline_relay as _relay
     from . import zero

@@ -63,7 +63,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
+from jax import shard_map
 from ..obs import flight as _flight
 from ..obs import metrics
 from .. import fault

@@ -14,7 +14,7 @@ from __future__ import annotations
 import jax
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
+from jax import shard_map
 
 from .. import device_api as dapi
 from ..communicator import Communicator

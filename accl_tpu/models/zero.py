@@ -1123,7 +1123,7 @@ def build_zero_fsdp_train_step(mesh, n_layers: int, d_model: int,
         return (ZeroFSDPState(unflat(new_p), unflat(new_m),
                               unflat(new_v), t_new), loss)
 
-    from ..compat import shard_map
+    from jax import shard_map
     specs = fsdp_param_specs(L)
     state_specs = ZeroFSDPState(p=specs, m=specs, v=specs, t=P())
     return jax.jit(shard_map(

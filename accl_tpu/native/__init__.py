@@ -12,6 +12,7 @@ framework works without a toolchain.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -20,7 +21,6 @@ from typing import Optional
 
 _SRC = Path(__file__).resolve().parent.parent.parent / "csrc" / "acclrt.cpp"
 _BUILD_DIR = Path(__file__).resolve().parent / "_build"
-_LIB = _BUILD_DIR / "libacclrt.so"
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -31,14 +31,23 @@ NO_MATCH = -1
 ERR_COUNT_MISMATCH = -2
 
 
-def _compile() -> bool:
+def _lib_path() -> Path:
+    """The built library, keyed on a hash of the source's CONTENTS: a
+    copied tree carries stale untracked builds with fresh mtimes, so
+    only the contents say whether a build matches the source."""
+    digest = hashlib.sha256(_SRC.read_bytes()).hexdigest()[:16]
+    return _BUILD_DIR / f"libacclrt-{digest}.so"
+
+
+def _compile() -> Optional[Path]:
+    """Path of a library built from the current source, or None."""
     if not _SRC.exists():
-        return False
+        return None
     try:
+        lib = _lib_path()
+        if lib.exists():
+            return lib
         _BUILD_DIR.mkdir(parents=True, exist_ok=True)
-        src_mtime = _SRC.stat().st_mtime
-        if _LIB.exists() and _LIB.stat().st_mtime >= src_mtime:
-            return True
         # build to a process-private path, then rename atomically so a
         # concurrent process can never dlopen a partially written library
         tmp = _BUILD_DIR / f".libacclrt.{os.getpid()}.so"
@@ -48,12 +57,12 @@ def _compile() -> bool:
                  str(_SRC), "-o", str(tmp)],
                 check=True, capture_output=True, timeout=120,
             )
-            os.replace(tmp, _LIB)
+            os.replace(tmp, lib)
         finally:
             tmp.unlink(missing_ok=True)
-        return True
+        return lib
     except (subprocess.SubprocessError, OSError):
-        return False
+        return None
 
 
 def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
@@ -137,9 +146,10 @@ def load() -> Optional[ctypes.CDLL]:
         _tried = True
         if os.environ.get("ACCL_NO_NATIVE"):
             return None
-        if _compile():
+        lib = _compile()
+        if lib is not None:
             try:
-                _lib = _bind(ctypes.CDLL(str(_LIB)))
+                _lib = _bind(ctypes.CDLL(str(lib)))
             except OSError:
                 _lib = None
         return _lib

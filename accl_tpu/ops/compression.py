@@ -23,6 +23,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..constants import dataType, to_jax_dtype
+from .reduce_ops import _interpret  # one rule for both plugin lanes
 
 _LANES = 128
 _BLOCK_ROWS = 256
@@ -34,10 +35,6 @@ CAST_PAIRS = (
     (dataType.float32, dataType.float16),
     (dataType.float16, dataType.float32),
 )
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _cast_kernel(x_ref, o_ref, *, dst):
@@ -122,7 +119,10 @@ def _sr_kernel(x_ref, seed_ref, o_ref, *, dst):
     # block — neighboring chunks of one payload rounding in lockstep,
     # the correlated-bias failure derive_seed exists to prevent at the
     # schedule level, reproduced at the tile level
-    pltpu.prng_seed(seed_ref[0], pl.program_id(0), pl.program_id(1))
+    # (Mosaic seeds from at most two values: the grid position folds
+    # into one)
+    pltpu.prng_seed(seed_ref[0],
+                    pl.program_id(0) * pl.num_programs(1) + pl.program_id(1))
     bits = pltpu.bitcast(pltpu.prng_random_bits(x_ref.shape), jnp.uint32)
     o_ref[:] = pltpu.stochastic_round(x_ref[:], bits, target_dtype=dst)
 
@@ -156,7 +156,7 @@ def pallas_compress_stochastic(x, dst_dtype, seed=0):
     compressing MULTIPLE legs of one schedule should decorrelate them
     via :func:`derive_seed` (each grid tile already folds its own grid
     position into the stream)."""
-    if jax.default_backend() != "tpu":  # stochastic_round is TPU-only
+    if _interpret():  # stochastic_round has no interpret-mode lowering
         return x.astype(dst_dtype)
     shape = x.shape
     tile = _BLOCK_ROWS * _LANES

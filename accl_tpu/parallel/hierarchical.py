@@ -51,7 +51,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
+from jax import shard_map
 
 from ..arithconfig import ArithConfig
 from ..communicator import Communicator

@@ -2,7 +2,7 @@
 
 The sibling :mod:`pallas_ring` kernels stage the whole payload in VMEM —
 correct for latency-sized messages but structurally unable to run the
-BASELINE.md sweep endpoint (1 GiB).  These kernels are the segmented
+BASELINE.json sweep endpoint (1 GiB).  These kernels are the segmented
 analog of the reference's streaming design: the firmware never holds a
 message, it cuts it into rx-buffer-sized segments and keeps a bounded
 number of moves in flight (send loop ``ccl_offload_control.c:628-649``,
@@ -1077,7 +1077,10 @@ def chunked_ar_body(x, *, P: int, func: reduceFunction, dtype,
         gathered = _chunked_ag_call(partial, P=P, C=C, sr=sr, dtype=dtype,
                                     bidirectional=bidirectional)
     if bidirectional:
-        segs = gathered.reshape(P, C, seg_elems)
+        # reorder with each segment kept in its (sr, lanes) tiling:
+        # flattening segments first made the TPU compiler take ~17 s at
+        # 64 MiB per rank and ~200 s at 1 GiB for the relayouts
+        segs = gathered.reshape(P, C, sr, _LANES)
         segs = segs.at[:, 0::2].set(jnp.roll(segs[:, 0::2], 1, axis=0))
         if C > 1:
             segs = segs.at[:, 1::2].set(jnp.roll(segs[:, 1::2], -1, axis=0))
@@ -1194,16 +1197,21 @@ def chunked_alltoall_body(x, *, P: int, dtype, segment_bytes: int,
     if P == 1:
         return x
     kdt = wire[0] if wire is not None else dtype
-    xin = x.reshape(P, n)
+    # chunks lanes-minor where they split into whole lanes: a (P, n)
+    # view under the dynamic row index below costs the TPU compiler
+    # time linear in n (primitives.lane_blocks)
+    lanes = n % _LANES == 0
+    xin = x.reshape((P, n // _LANES, _LANES) if lanes else (P, n))
     wired = (_pr._to_wire(xin, wire) if wire is not None
              else xin.astype(dtype))
     C, sr, seg_elems = _geometry(n, kdt, segment_bytes)
     per = C * seg_elems
-    grid = jnp.zeros((P, per), kdt)
-    grid = lax.dynamic_update_slice(grid, wired, (0, 0))
+    grid = jnp.zeros((P, C * sr, _LANES) if lanes else (P, per), kdt)
+    grid = lax.dynamic_update_slice(grid, wired, (0,) * grid.ndim)
     out = _chunked_alltoall_call(
         grid.reshape(P, C, sr, _LANES), P=P, C=C, sr=sr, dtype=kdt)
-    blocks = out.reshape(P, per)[:, :n]
+    blocks = (out.reshape(P, C * sr, _LANES)[:, :n // _LANES] if lanes
+              else out.reshape(P, per)[:, :n])
     blocks = (_pr._from_wire(blocks, dtype, wire) if wire is not None
               else blocks).astype(x.dtype)
     # own chunk stays local (never on the wire; o_ref[my] is unwritten)

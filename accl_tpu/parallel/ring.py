@@ -33,7 +33,7 @@ from ..arithconfig import ArithConfig
 from ..communicator import Communicator
 from ..constants import dataType, reduceFunction
 from .. import ops
-from .primitives import AXIS, _smap
+from .primitives import AXIS, LANES, _smap, lane_blocks
 
 Array = jax.Array
 
@@ -73,9 +73,10 @@ def build_ring_allreduce(
 
     def body(x):
         n = x.shape[-1]
-        chunk = -(-n // world)  # ceil
+        # ceil, in whole lanes: chunks stay lanes-minor (lane_blocks)
+        chunk = -(-n // (world * LANES)) * LANES
         padded = jnp.pad(x[0], (0, chunk * world - n))
-        chunks = padded.reshape(world, chunk)
+        chunks = lane_blocks(padded, world)
         rank = lax.axis_index(AXIS)
 
         # phase 1: ring reduce-scatter — at step s rank r sends partial chunk
@@ -116,9 +117,9 @@ def build_ring_allgather(comm: Communicator,
     def body(x):
         n = x.shape[-1]
         rank = lax.axis_index(AXIS)
-        out = jnp.zeros((world, n), dtype=x.dtype)
-        out = lax.dynamic_update_index_in_dim(out, x[0], rank, axis=0)
-        buf = x[0]
+        buf = lane_blocks(x[0], 1)[0]
+        out = jnp.zeros((world,) + buf.shape, dtype=x.dtype)
+        out = lax.dynamic_update_index_in_dim(out, buf, rank, axis=0)
         for s in range(world - 1):  # static: perm identical each step
             buf = _hop(buf, world, arith)
             src = jnp.mod(rank - s - 1, world)
@@ -139,7 +140,7 @@ def build_ring_reduce_scatter(
     world = comm.world_size
 
     def body(x):
-        chunks = x.reshape(world, -1)
+        chunks = lane_blocks(x, world)
         rank = lax.axis_index(AXIS)
 
         def rs_step(s, ch):

@@ -1494,7 +1494,7 @@ def _smapnd(comm, axes: Tuple[int, ...], body) -> Callable:
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from ..compat import shard_map
+    from jax import shard_map
 
     names = _axis_names(len(axes))
     mesh = comm.meshnd(axes, names)
@@ -1549,7 +1549,7 @@ def build_multiaxis_allreduce(comm, axes, func: reduceFunction,
     from jax import lax
 
     from .. import ops
-    from .primitives import _unwire, _wire
+    from .primitives import _unwire, _wire, lane_blocks
 
     axes = _norm_axes(comm, axes)
     nd, world = len(axes), _prod(axes)
@@ -1566,7 +1566,7 @@ def build_multiaxis_allreduce(comm, axes, func: reduceFunction,
         if func == reduceFunction.SUM and not decompress_arith:
             for ax in reversed(range(nd)):
                 ph.append(lambda t, ax=ax: lax.psum_scatter(
-                    t.reshape(axes[ax], -1), names[ax],
+                    lane_blocks(t, axes[ax]), names[ax],
                     scatter_dimension=0, tiled=False))
             for ax in range(nd):
                 ph.append(lambda t, ax=ax: lax.all_gather(
@@ -1579,7 +1579,7 @@ def build_multiaxis_allreduce(comm, axes, func: reduceFunction,
             for ax in reversed(range(nd)):
                 ph.append(lambda t, ax=ax: ops.reduce_axis0(
                     _unwire(lax.all_to_all(
-                        _wire(t, arith).reshape(axes[ax], -1), names[ax],
+                        lane_blocks(_wire(t, arith), axes[ax]), names[ax],
                         split_axis=0, concat_axis=0), arith, x_dtype),
                     func, dt))
             ph.append(lambda t: lax.all_gather(_wire(t, arith), names[0],
@@ -1603,8 +1603,8 @@ def build_multiaxis_allreduce(comm, axes, func: reduceFunction,
         if pad:
             x = jnp.pad(x, (0, pad))
         phases = _phases(v.dtype, x.dtype)
-        parts = list(x.reshape(C, -1)) if C > 1 else [x]
-        outs = _wavefront(parts, phases)
+        parts = list(lane_blocks(x, C)) if C > 1 else [x]
+        outs = [o.reshape(-1) for o in _wavefront(parts, phases)]
         out = jnp.concatenate(outs) if C > 1 else outs[0]
         out = out[:n] if pad else out
         return out.reshape((1,) * nd + (n,))
@@ -1625,7 +1625,7 @@ def build_multiaxis_reduce_scatter(comm, axes, func: reduceFunction,
     from jax import lax
 
     from .. import ops
-    from .primitives import _unwire, _wire
+    from .primitives import LANES, _unwire, _wire, lane_blocks
 
     axes = _norm_axes(comm, axes)
     nd, world = len(axes), _prod(axes)
@@ -1637,26 +1637,31 @@ def build_multiaxis_reduce_scatter(comm, axes, func: reduceFunction,
     # one leaves rank (r0..r_{nd-1}) holding exactly its flat chunk
     perm = tuple(reversed(range(nd))) + (nd,)
 
+    def _permute(t, pc):
+        # lanes-minor when the piece splits into whole lanes (lane_blocks)
+        if pc % LANES == 0:
+            t = t.reshape(axes + (pc // LANES, LANES))
+            return lane_blocks(t.transpose(perm + (nd + 1,)), axes[-1])
+        return lane_blocks(t.reshape(axes + (pc,)).transpose(perm),
+                           axes[-1])
+
     def _phases(out_dtype, x_dtype, pc):
-        prep = [lambda t: _wire(
-            t.reshape(axes + (pc,)).transpose(perm).reshape(axes[-1], -1),
-            arith)]
+        prep = [lambda t: _wire(_permute(t, pc), arith)]
         if func == reduceFunction.SUM and not decompress_arith:
             ph = prep
             for ax in reversed(range(nd)):
                 ph.append(lambda t, ax=ax: lax.psum_scatter(
-                    t.reshape(axes[ax], -1), names[ax],
+                    lane_blocks(t, axes[ax]), names[ax],
                     scatter_dimension=0, tiled=False))
             ph.append(lambda t: _unwire(t, arith, out_dtype))
         else:
             # general path (MAX, decompress-before-arith): per-axis
             # chunk exchange + rank-ordered local fold at full precision
-            ph = [lambda t: t.reshape(axes + (pc,)).transpose(perm)
-                  .reshape(axes[-1], -1)]
+            ph = [lambda t: _permute(t, pc)]
             for ax in reversed(range(nd)):
                 ph.append(lambda t, ax=ax: ops.reduce_axis0(
                     _unwire(lax.all_to_all(
-                        _wire(t, arith).reshape(axes[ax], -1), names[ax],
+                        lane_blocks(_wire(t, arith), axes[ax]), names[ax],
                         split_axis=0, concat_axis=0), arith, x_dtype),
                     func, dt))
             ph.append(lambda t: t.astype(out_dtype))
@@ -1667,14 +1672,19 @@ def build_multiaxis_reduce_scatter(comm, axes, func: reduceFunction,
         count = x.shape[-1] // world
         pc = -(-count // C)
         padc = pc * C - count
-        t = x.reshape(world, count)
-        if padc:
-            t = jnp.pad(t, ((0, 0), (0, padc)))
+        t = (jnp.pad(x.reshape(world, count), ((0, 0), (0, padc)))
+             if padc else x)
         phases = _phases(v.dtype, x.dtype, pc)
         # chunk c's input: piece c of every rank's destined block
-        tc = t.reshape(world, C, pc).transpose(1, 0, 2)  # (C, world, pc)
-        parts = [tc[c].reshape(-1) for c in range(C)]
-        outs = _wavefront(parts, phases)
+        if C == 1:
+            parts = [t]
+        elif pc % LANES == 0:
+            tc = t.reshape(world, C, pc // LANES, LANES).transpose(1, 0, 2, 3)
+            parts = [tc[c] for c in range(C)]
+        else:
+            tc = t.reshape(world, C, pc).transpose(1, 0, 2)  # (C, world, pc)
+            parts = [tc[c] for c in range(C)]
+        outs = [o.reshape(-1) for o in _wavefront(parts, phases)]
         out = jnp.concatenate(outs)[:count] if (C > 1 or padc) else outs[0]
         return out.reshape((1,) * nd + (count,))
 
@@ -1690,22 +1700,30 @@ def build_multiaxis_allgather(comm, axes, arith=None,
     import jax.numpy as jnp
     from jax import lax
 
-    from .primitives import _unwire, _wire
+    from .primitives import _unwire, _wire, lane_blocks
 
     axes = _norm_axes(comm, axes)
     nd, world = len(axes), _prod(axes)
     names = _axis_names(nd)
     C = max(1, int(pipeline_chunks))
-    # gathered leading dims accumulate as (s_{nd-1}, ..., s_0): reverse
-    # them so index (r0, ..., r_{nd-1}) flattens to the flat chunk order
-    perm = tuple(reversed(range(nd))) + (nd,)
 
     def _phases(out_dtype, pc):
-        ph = [lambda t: lax.all_gather(_wire(t, arith), names[0])]
+        # each piece lanes-minor (lane_blocks): (rows, LANES) or (pc,)
+        ph = [lambda t: lax.all_gather(
+            _wire(lane_blocks(t, 1)[0], arith), names[0])]
         for ax in range(1, nd):
             ph.append(lambda t, ax=ax: lax.all_gather(t, names[ax]))
-        ph.append(lambda t: _unwire(t, arith, out_dtype)
-                  .transpose(perm).reshape(world, pc))
+
+        def unpermute(t):
+            # gathered leading dims accumulate as (s_{nd-1}, ..., s_0):
+            # reverse them so index (r0, ..., r_{nd-1}) flattens to the
+            # flat chunk order
+            tail = t.ndim - nd
+            perm = (tuple(reversed(range(nd)))
+                    + tuple(range(nd, nd + tail)))
+            t = _unwire(t, arith, out_dtype).transpose(perm)
+            return t.reshape((world,) + t.shape[nd:])
+        ph.append(unpermute)
         return ph
 
     def body(v):  # (1,)*nd + (count,) -> (1,)*nd + (world*count,)
@@ -1716,11 +1734,13 @@ def build_multiaxis_allgather(comm, axes, arith=None,
         if padc:
             x = jnp.pad(x, (0, padc))
         phases = _phases(v.dtype, pc)
-        parts = list(x.reshape(C, pc))
-        outs = _wavefront(parts, phases)       # each (world, pc)
+        parts = list(lane_blocks(x, C))
+        outs = _wavefront(parts, phases)       # each (world, <piece>)
         if C > 1 or padc:
-            out = jnp.stack(outs, axis=1).reshape(world, C * pc)
-            out = out[:, :count].reshape(-1)
+            out = jnp.stack(outs, axis=1)      # (world, C, <piece>)
+            if padc:
+                out = out.reshape(world, C * pc)[:, :count]
+            out = out.reshape(-1)
         else:
             out = outs[0].reshape(-1)
         return out.reshape((1,) * nd + (world * count,))

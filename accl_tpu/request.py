@@ -114,6 +114,10 @@ class Request:
             else:
                 self._duration_ns = time.monotonic_ns() - self._start_ns
             self._done = True
+            # a completed request no longer pins its device outputs: the
+            # queue's bounded history would otherwise keep up to 256
+            # payloads alive on the device
+            self._outputs = None
             self._cv.notify_all()
         # retirement telemetry: completion counts by terminal status and
         # the whole-request latency (issue -> complete, the PERFCNT

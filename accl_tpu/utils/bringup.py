@@ -91,18 +91,7 @@ def simulated_devices(n: int) -> List[jax.Device]:
 
     jax.clear_caches()
     _jax_backend.clear_backends()
-    try:
-        jax.config.update("jax_num_cpu_devices", n)
-    except AttributeError:
-        # older jax has no jax_num_cpu_devices option: the XLA_FLAGS
-        # spelling is re-read when the backend re-initializes after
-        # clear_backends above
-        import os
-        flags = [f for f in os.environ.get("XLA_FLAGS", "").split()
-                 if not f.startswith(
-                     "--xla_force_host_platform_device_count")]
-        flags.append(f"--xla_force_host_platform_device_count={n}")
-        os.environ["XLA_FLAGS"] = " ".join(flags)
+    jax.config.update("jax_num_cpu_devices", n)
     jax.config.update("jax_platforms", "cpu")
     devices = jax.devices()
     if len(devices) < n:

@@ -3,8 +3,8 @@
 
 Multi-rank configs run on the CPU-emulator rung (the reference's numbers
 for multi-rank also come from its emulator in CI — SURVEY.md §4); the
-single-chip datapath row comes from ``bench.py`` on the real TPU. Results
-fill the "Targets for the TPU build" table in BASELINE.md.
+single-chip datapath row comes from ``bench.py`` on the real TPU. CPU-rung
+results are correctness evidence, never device numbers.
 
 Usage::
 

@@ -26,15 +26,10 @@ import numpy as np
 
 import accl_tpu
 from accl_tpu import dataType
+from accl_tpu.utils import compile_cache
 
 import jax
 
-# persistent compile cache: the pair-mesh move programs recompile on
-# every fresh launcher process otherwise, polluting the first-window ramp
-jax.config.update("jax_compilation_cache_dir", os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-    ".jax_cache"))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
 
 def _bw_gbps(nbytes: int, seconds: float) -> float:
@@ -42,6 +37,11 @@ def _bw_gbps(nbytes: int, seconds: float) -> float:
 
 
 def main() -> int:
+    # persistent compile cache: the pair-mesh move programs recompile on
+    # every fresh launcher process otherwise, polluting the first-window
+    # ramp
+    compile_cache.enable(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
     me = jax.process_index()
     # 64-segment (1 MiB) eager window: the batched-accept mover amortizes
     # one pair-collective entry over the whole window, so sustained eager

@@ -507,17 +507,21 @@ def test_autotune_collective_matmul_sweeps_streaming_shapes(accl,
     assert plan is None or plan["mode"] == "stream"
 
 
-def test_autotune_zero_fsdp_gates(accl):
+def test_autotune_zero_fsdp_gates(accl, monkeypatch):
     """The layerwise ZeRO schedule register tunes only where a
     measurement would mean something: off ICI the config passes through
     untouched, and on ICI a rung whose kernels cannot run (so the fused
     step would measure its own committed fallback) also passes through
     — zero_overlap keeps its session value either way."""
     from accl_tpu.config import TransportBackend
+    from accl_tpu.ops import collective_matmul as cm
 
     cfg = autotune.autotune_zero_fsdp(accl)         # SIM transport
     assert cfg.zero_overlap == accl.config.zero_overlap
     orig = accl.config
+    # the TPU interpreter runs the kernels on this rung: stand in for one
+    # that cannot
+    monkeypatch.setattr(cm, "_kernels_available", lambda: False)
     try:
         accl.config = accl.config.replace(transport=TransportBackend.ICI)
         cfg = autotune.autotune_zero_fsdp(accl)     # ICI, no kernels here

@@ -44,3 +44,34 @@ def test_initialize_accl_simulator_ranks_reuses_cpu_mesh():
         assert acc.parse_hwid()["platform"] == "cpu"
     finally:
         acc.deinit()
+
+
+def test_dryrun_multichip_fails_without_the_devices():
+    """The multi-chip dry run never moves itself onto a CPU mesh: with
+    fewer devices than asked it fails and names the CPU entry."""
+    import __graft_entry__ as graft
+
+    with pytest.raises(RuntimeError, match="__graft_entry__.py 64"):
+        graft.dryrun_multichip(64)
+
+
+@pytest.mark.parametrize("env_dir", [None, "/some/cache"])
+def test_compile_cache_location(monkeypatch, tmp_path, env_dir):
+    """JAX_COMPILATION_CACHE_DIR, when set, is JAX's own to read and no
+    other directory is set; otherwise <checkout>/.jax_cache."""
+    from accl_tpu.utils import compile_cache
+
+    updates = {}
+    monkeypatch.setattr(compile_cache.jax.config, "update",
+                        lambda k, v: updates.__setitem__(k, v))
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    got = compile_cache.enable(str(tmp_path))
+    if env_dir is None:
+        assert got == str(tmp_path / ".jax_cache")
+        assert updates["jax_compilation_cache_dir"] == got
+    else:
+        assert got == env_dir
+        assert "jax_compilation_cache_dir" not in updates

@@ -6,7 +6,6 @@ import numpy as np
 import pytest
 
 from accl_tpu import ACCLError, dataType, errorCode, reduceFunction
-from conftest import requires_interpret_rdma
 
 WORLD = 8
 
@@ -255,7 +254,6 @@ def test_cmdlist_from_device_skips_host_upload(accl, rng):
     np.testing.assert_array_equal(y.host, np.tile(first.sum(0), (WORLD, 1)))
 
 
-@requires_interpret_rdma
 def test_cmdlist_fuses_chunked_pallas_step(accl, rng):
     """A recorded list mixing a Pallas chunked collective with jnp-family
     steps compiles and launches as one fused program — the segmented

@@ -4,10 +4,10 @@ MoE dispatch/combine datapath with the wire hidden under expert compute.
 Parity is BIT-exact fp32 against the unfused ``lax.all_to_all`` + einsum
 pair: operands are integer-valued floats (every product and partial sum
 is exactly representable), so any reassociation the exchange schedule
-introduces cannot hide behind tolerance. Kernel suites need simulated
-remote DMA (``requires_interpret_rdma``); the policy/plan/fallback/moe
-tests run on every rung — the entry points resolve to the unfused pair
-where kernels cannot run, same math by construction.
+introduces cannot hide behind tolerance. Kernel suites run under the
+TPU interpreter's simulated remote DMA; the policy/plan/fallback/moe
+tests check that the entry points resolve to the unfused pair where a
+plan declines, same math by construction.
 """
 import numpy as np
 import pytest
@@ -20,7 +20,6 @@ from accl_tpu.communicator import Communicator
 from accl_tpu.ops import collective_alltoall as ca
 from accl_tpu.ops import collective_matmul as cm
 from accl_tpu.parallel import algorithms, pallas_ring
-from conftest import requires_interpret_rdma
 
 WORLD = 8
 
@@ -81,7 +80,6 @@ def _host_combine(h, w):
 # interpreter parity: fused kernels vs the unfused pair, bit-exact
 # ---------------------------------------------------------------------------
 
-@requires_interpret_rdma
 @pytest.mark.parametrize("W", [2, 4, 8])
 @pytest.mark.parametrize("shape", [(2, 8, 128, 128),   # dense, tile-aligned
                                    (2, 5, 72, 40)])    # uneven, padded
@@ -97,7 +95,6 @@ def test_a2amm_parity_bit_exact(accl, rng, W, shape):
         fused, _host_dispatch(x, w).astype(np.float32))
 
 
-@requires_interpret_rdma
 @pytest.mark.parametrize("W", [4, 8])
 @pytest.mark.parametrize("shape", [(2, 8, 128, 128), (2, 5, 72, 40)])
 def test_a2amm_parity_bidirectional(accl, rng, W, shape):
@@ -113,7 +110,6 @@ def test_a2amm_parity_bidirectional(accl, rng, W, shape):
     np.testing.assert_array_equal(fused, ref)
 
 
-@requires_interpret_rdma
 @pytest.mark.parametrize("W", [2, 4, 8])
 @pytest.mark.parametrize("shape", [(2, 8, 128, 128), (2, 5, 72, 40)])
 def test_mma2a_parity_bit_exact(accl, rng, W, shape):
@@ -128,7 +124,6 @@ def test_mma2a_parity_bit_exact(accl, rng, W, shape):
         fused, _host_combine(hx, w).astype(np.float32))
 
 
-@requires_interpret_rdma
 @pytest.mark.parametrize("W", [4, 8])
 @pytest.mark.parametrize("shape", [(2, 8, 128, 128), (2, 5, 72, 40)])
 def test_mma2a_parity_bidirectional(accl, rng, W, shape):
@@ -141,7 +136,6 @@ def test_mma2a_parity_bidirectional(accl, rng, W, shape):
     np.testing.assert_array_equal(fused, ref)
 
 
-@requires_interpret_rdma
 def test_a2a_race_free(accl, rng, monkeypatch):
     """Both flat-exchange kernels, uni- and bidirectional, under the
     interpret-mode race detector: the dispatch credit protocol (grants
@@ -167,7 +161,6 @@ def test_a2a_race_free(accl, rng, monkeypatch):
             fused, _run_mma2a(comm, hx, w_out, Algorithm.XLA, bidir))
 
 
-@requires_interpret_rdma
 def test_a2a_grads_through_kernels(accl, rng):
     """The custom VJPs (each kernel's backward dx is the other kernel)
     match the grads of the unfused pair — same integer-exactness."""
@@ -205,7 +198,6 @@ def test_a2a_grads_through_kernels(accl, rng):
     np.testing.assert_array_equal(np.asarray(go_f), np.asarray(go_r))
 
 
-@requires_interpret_rdma
 def test_a2a_wire_bit_exact_with_f32_accumulate(accl, rng):
     """bf16 wire staging for dispatch rounds the token payload once:
     with small-integer operands (bf16-lossless) the wire path is
@@ -223,7 +215,6 @@ def test_a2a_wire_bit_exact_with_f32_accumulate(accl, rng):
     np.testing.assert_array_equal(fused, ref)
 
 
-@requires_interpret_rdma
 def test_mma2a_wire_tolerance(accl, rng):
     """bf16 wire for combine rounds each travelling y block once (local
     block included, for uniform semantics) — tolerance-bounded vs the
@@ -247,7 +238,6 @@ def test_mma2a_wire_tolerance(accl, rng):
     np.testing.assert_array_equal(fused, ref)
 
 
-@requires_interpret_rdma
 def test_moe_fused_matches_baseline_kernels(accl, rng):
     """The flagship consumer on the kernel rung: build_moe_forward with
     the fused datapath engaged matches the lax baseline to float
@@ -419,7 +409,7 @@ def test_select_a2a_operations(accl):
 
 
 def test_a2a_body_rejects_bad_shapes(accl):
-    from accl_tpu.compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     mesh = Mesh(np.array(jax.devices()[:4]), ("accl",))
@@ -475,7 +465,7 @@ def test_a2a_device_api_entry_points(accl, rng):
 # ---------------------------------------------------------------------------
 
 def _trace_a2a(monkeypatch, fn, xshape, wshape, out_spec=None):
-    from accl_tpu.compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     monkeypatch.setattr(cm, "_kernels_available", lambda: True)
@@ -545,7 +535,7 @@ def test_a2a_vjp_traces_fused_dual(accl, monkeypatch):
     versa), and the fused a2a-wgrad dw kernel (the gradient exchange
     folded into the per-expert contraction sweep). No unfused
     ``all_to_all`` survives in the backward."""
-    from accl_tpu.compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     monkeypatch.setattr(cm, "_kernels_available", lambda: True)
@@ -579,7 +569,7 @@ def test_a2a_fallback_counter_reasons(accl, monkeypatch):
     """accl_cmatmul_fallback_total generalizes to the a2a ops: every
     fused-path fallback counted by reason, the warn-once set dedupes
     only the log, an explicit overlap=False is never counted."""
-    from accl_tpu.compat import shard_map
+    from jax import shard_map
     from accl_tpu.obs import metrics as obs_metrics
     from jax.sharding import Mesh, PartitionSpec as P
 

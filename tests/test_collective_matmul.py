@@ -4,10 +4,10 @@ all-gather x matmul and matmul x reduce-scatter.
 Parity is BIT-exact fp32 against the unfused XLA pair: operands are
 integer-valued floats (every product and partial sum is exactly
 representable), so any reassociation the ring schedule introduces cannot
-hide behind tolerance. Kernel suites need simulated remote DMA
-(``requires_interpret_rdma``); the policy/fallback/model tests run on
-every rung — the overlapped entry points resolve to the unfused pair
-where kernels cannot run, same math by construction.
+hide behind tolerance. Kernel suites run under the TPU interpreter's
+simulated remote DMA; the policy/fallback/model tests check that the
+overlapped entry points resolve to the unfused pair where a plan
+declines, same math by construction.
 """
 import numpy as np
 import pytest
@@ -19,7 +19,6 @@ from accl_tpu import Algorithm
 from accl_tpu.communicator import Communicator
 from accl_tpu.ops import collective_matmul as cm
 from accl_tpu.parallel import algorithms, pallas_ring
-from conftest import requires_interpret_rdma
 
 WORLD = 8
 
@@ -53,7 +52,6 @@ def _run_mmrs(comm, x, w, algo, bidirectional):
 # interpreter parity: fused kernels vs the unfused XLA pair, bit-exact
 # ---------------------------------------------------------------------------
 
-@requires_interpret_rdma
 @pytest.mark.parametrize("W", [2, 4, 8])
 @pytest.mark.parametrize("shape", [(16, 128, 128),    # dense, tile-aligned
                                    (12, 72, 40)])     # uneven-divisible
@@ -71,7 +69,6 @@ def test_agmm_parity_bit_exact(accl, rng, W, shape):
         np.testing.assert_array_equal(fused[r], gathered @ w[r])
 
 
-@requires_interpret_rdma
 @pytest.mark.parametrize("W", [4, 8])
 @pytest.mark.parametrize("shape", [(16, 128, 128), (12, 72, 40)])
 def test_agmm_parity_bidirectional(accl, rng, W, shape):
@@ -86,7 +83,6 @@ def test_agmm_parity_bidirectional(accl, rng, W, shape):
     np.testing.assert_array_equal(fused, ref)
 
 
-@requires_interpret_rdma
 @pytest.mark.parametrize("W", [2, 4, 8])
 @pytest.mark.parametrize("shape", [(16, 128, 128), (12, 72, 40)])
 def test_mmrs_parity_bit_exact(accl, rng, W, shape):
@@ -106,7 +102,6 @@ def test_mmrs_parity_bit_exact(accl, rng, W, shape):
             fused[r], host[r * m:(r + 1) * m].astype(np.float32))
 
 
-@requires_interpret_rdma
 @pytest.mark.parametrize("W", [4, 8])
 @pytest.mark.parametrize("shape", [(16, 128, 128), (12, 72, 40)])
 def test_mmrs_parity_bidirectional(accl, rng, W, shape):
@@ -119,7 +114,6 @@ def test_mmrs_parity_bidirectional(accl, rng, W, shape):
     np.testing.assert_array_equal(fused, ref)
 
 
-@requires_interpret_rdma
 def test_cmatmul_race_free(accl, rng, monkeypatch):
     """Both ring kernels, uni- and bidirectional, under the interpret-mode
     race detector: the double-buffer credit protocol (grants == gates)
@@ -143,7 +137,6 @@ def test_cmatmul_race_free(accl, rng, monkeypatch):
         np.testing.assert_array_equal(fused, ref)
 
 
-@requires_interpret_rdma
 def test_cmatmul_grads_through_kernels(accl, rng):
     """The custom VJPs (each kernel's backward is the other kernel) match
     the grads of the unfused pair — same integer-exactness trick."""
@@ -209,7 +202,7 @@ def test_overlap_off_never_traces_kernels(accl, monkeypatch):
     """overlap=False (per call) and oversized plans pin the unfused XLA
     pair — no pallas_call may appear in the traced program. (Kernel
     availability is forced so the assertion bites on every rung.)"""
-    from accl_tpu.compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     monkeypatch.setattr(cm, "_kernels_available", lambda: True)
@@ -247,7 +240,7 @@ def test_session_config_write_through(accl):
 
 
 def test_body_rejects_bad_shapes(accl):
-    from accl_tpu.compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     mesh = Mesh(np.array(jax.devices()[:4]), ("accl",))
@@ -397,7 +390,7 @@ def test_threshold_write_through_gates_session_default(accl, monkeypatch):
     fused-vs-XLA (DISABLED pins the pair), while an explicit
     overlap=True bypasses them (the per-call force)."""
     from accl_tpu.bench.autotune import DISABLED
-    from accl_tpu.compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     monkeypatch.setattr(cm, "_kernels_available", lambda: True)
@@ -647,7 +640,7 @@ def test_select_sees_effective_wire_bytes(accl):
 # ---------------------------------------------------------------------------
 
 def _trace_body(monkeypatch, fn, xshape, wshape, out_spec=None):
-    from accl_tpu.compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     monkeypatch.setattr(cm, "_kernels_available", lambda: True)
@@ -701,7 +694,7 @@ def test_vjp_traces_fused_dw(accl, monkeypatch):
     """Both custom VJPs now trace THREE fused kernels: the forward, the
     dual dx kernel, and the fused gathered-wgrad dw kernel (dw was an
     unfused all_gather + matmul through round 8)."""
-    from accl_tpu.compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     monkeypatch.setattr(cm, "_kernels_available", lambda: True)
@@ -731,7 +724,7 @@ def test_fallback_counter_reasons(accl, monkeypatch):
     """accl_cmatmul_fallback_total counts EVERY fused-path fallback
     labelled by reason — what the warn-once log hides (ISSUE r9). An
     explicit overlap=False is a requested XLA pair, never counted."""
-    from accl_tpu.compat import shard_map
+    from jax import shard_map
     from accl_tpu.obs import metrics as obs_metrics
     from jax.sharding import Mesh, PartitionSpec as P
 
@@ -831,7 +824,7 @@ def test_wgrad_body_both_orientations(accl, rng):
 
 
 def test_wgrad_body_rejects_row_mismatch(accl):
-    from accl_tpu.compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     mesh = Mesh(np.array(jax.devices()[:4]), ("accl",))
@@ -856,7 +849,6 @@ def _budget(monkeypatch, nbytes):
     monkeypatch.setattr(cm, "_VMEM_BUDGET", nbytes)
 
 
-@requires_interpret_rdma
 @pytest.mark.parametrize("W", [2, 4, 8])
 @pytest.mark.parametrize("bidir", [False, True])
 def test_agmm_stream_parity_bit_exact(accl, rng, monkeypatch, W, bidir):
@@ -878,7 +870,6 @@ def test_agmm_stream_parity_bit_exact(accl, rng, monkeypatch, W, bidir):
     np.testing.assert_array_equal(fused, ref)
 
 
-@requires_interpret_rdma
 def test_agmm_stream_parity_real_budget(accl, rng):
     """The acceptance shape: a shard whose RESIDENT plan misses the real
     12 MiB budget (w block alone is 16 MiB) — previously fell back to
@@ -894,7 +885,6 @@ def test_agmm_stream_parity_real_budget(accl, rng):
     np.testing.assert_array_equal(fused, ref)
 
 
-@requires_interpret_rdma
 @pytest.mark.parametrize("W", [2, 4, 8])
 @pytest.mark.parametrize("bidir", [False, True])
 def test_mmrs_stream_parity_bit_exact(accl, rng, monkeypatch, W, bidir):
@@ -913,7 +903,6 @@ def test_mmrs_stream_parity_bit_exact(accl, rng, monkeypatch, W, bidir):
     np.testing.assert_array_equal(fused, ref)
 
 
-@requires_interpret_rdma
 def test_stream_race_free(accl, rng, monkeypatch):
     """The streaming kernels under the interpret-mode race detector:
     the segment-level credit protocol (grants == gates, store-and-
@@ -940,7 +929,6 @@ def test_stream_race_free(accl, rng, monkeypatch):
             fused, _run_mmrs(comm, x_rs, w, Algorithm.XLA, bidir))
 
 
-@requires_interpret_rdma
 @pytest.mark.parametrize("W", [2, 4, 8])
 def test_fused_wgrad_parity_bit_exact(accl, rng, W):
     """The fused dgrad/wgrad backward matches the unfused VJP bit-exact
@@ -971,7 +959,6 @@ def test_fused_wgrad_parity_bit_exact(accl, rng, W):
     np.testing.assert_array_equal(g_fused, g_ref)
 
 
-@requires_interpret_rdma
 def test_wgrad_race_free(accl, rng, monkeypatch):
     from jax.experimental.pallas import tpu as pltpu
     from jax.sharding import PartitionSpec as P
@@ -1003,7 +990,6 @@ def test_wgrad_race_free(accl, rng, monkeypatch):
                     got[r], want.astype(np.float32))
 
 
-@requires_interpret_rdma
 def test_agmm_wire_bit_exact_with_f32_accumulate(accl, rng):
     """bf16 wire staging for agmm rounds the INPUT shard once: with
     small-integer operands (exactly bf16-representable) the fused wire
@@ -1024,7 +1010,6 @@ def test_agmm_wire_bit_exact_with_f32_accumulate(accl, rng):
     np.testing.assert_array_equal(fused, ref)
 
 
-@requires_interpret_rdma
 def test_mmrs_wire_tolerance(accl, rng):
     """bf16 wire staging for mmrs rounds the travelling PARTIAL SUM once
     per hop — tolerance-bounded vs the f32 pair (docs/kernels.md states
@@ -1093,7 +1078,7 @@ def test_wgrad_wire_traces(accl, monkeypatch):
     the fold — lax.dot_general requires matching operand dtypes, so a
     bf16 arrival meeting a f32 local block must cast inside the kernel
     (regression: round-9 review)."""
-    from accl_tpu.compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     monkeypatch.setattr(cm, "_kernels_available", lambda: True)
@@ -1208,7 +1193,7 @@ def test_wire_sr_threads_through_kernels(accl, monkeypatch):
                 ts, ls, axis="accl", overlap=True, wire_dtype="bf16_sr",
                 travel_lhs=lhs)
 
-        from accl_tpu.compat import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         mesh = Mesh(np.array(jax.devices()[:4]), ("accl",))
         t = str(jax.make_jaxpr(shard_map(
@@ -1224,7 +1209,6 @@ def test_wire_sr_threads_through_kernels(accl, monkeypatch):
 # (parity needs simulated remote DMA; the trace/plan tests run anywhere)
 # ---------------------------------------------------------------------------
 
-@requires_interpret_rdma
 @pytest.mark.parametrize("W", [2, 4, 8])
 @pytest.mark.parametrize("bidir", [False, True])
 def test_agmm_nblock_parity_bit_exact(accl, rng, monkeypatch, W, bidir):
@@ -1247,7 +1231,6 @@ def test_agmm_nblock_parity_bit_exact(accl, rng, monkeypatch, W, bidir):
     np.testing.assert_array_equal(fused, ref)
 
 
-@requires_interpret_rdma
 @pytest.mark.parametrize("W", [2, 4, 8])
 @pytest.mark.parametrize("bidir", [False, True])
 def test_mmrs_nblock_parity_bit_exact(accl, rng, monkeypatch, W, bidir):
@@ -1269,7 +1252,6 @@ def test_mmrs_nblock_parity_bit_exact(accl, rng, monkeypatch, W, bidir):
     np.testing.assert_array_equal(fused, ref)
 
 
-@requires_interpret_rdma
 @pytest.mark.parametrize("W", [2, 4, 8])
 def test_wgrad_nblock_parity_bit_exact(accl, rng, monkeypatch, W):
     """ct-blocked streaming wgrad: each ctb column block of the
@@ -1307,7 +1289,7 @@ def test_nblock_traces_one_kernel_per_block(accl, monkeypatch):
     block: the traced program carries exactly nmb pallas_calls (agmm)
     / nnb (mmrs) — the block loop is unrolled at trace time, so the
     count is the plan's, not a rounding accident."""
-    from accl_tpu.compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     monkeypatch.setattr(cm, "_kernels_available", lambda: True)

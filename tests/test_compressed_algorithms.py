@@ -7,7 +7,6 @@ import numpy as np
 import pytest
 
 from accl_tpu import Algorithm, dataType, reduceFunction
-from conftest import requires_interpret_rdma, skip_unless_interpret_rdma
 
 WORLD = 8
 # small ints survive bf16/f16 wire casts exactly (|x| < 256 integer grid)
@@ -23,8 +22,6 @@ def _small_ints(rng, shape):
 @pytest.mark.parametrize("wire", [dataType.bfloat16, dataType.float16])
 @pytest.mark.parametrize("count", [33, 1021])
 def test_bcast_compressed_algorithms(accl, rng, algo, wire, count):
-    if algo is Algorithm.PALLAS:
-        skip_unless_interpret_rdma()
     buf = accl.create_buffer(count, dataType.float32)
     buf.host[:] = _small_ints(rng, (WORLD, count))
     expect = buf.host[3].copy()
@@ -37,8 +34,6 @@ def test_bcast_compressed_algorithms(accl, rng, algo, wire, count):
                                   Algorithm.FLAT, Algorithm.PALLAS])
 @pytest.mark.parametrize("func", [reduceFunction.SUM, reduceFunction.MAX])
 def test_reduce_compressed_algorithms(accl, rng, algo, func):
-    if algo is Algorithm.PALLAS:
-        skip_unless_interpret_rdma()
     count = 47
     send = accl.create_buffer(count, dataType.float32)
     recv = accl.create_buffer(count, dataType.float32)
@@ -68,8 +63,6 @@ def test_allreduce_compressed_algorithms(accl, rng, algo):
 @pytest.mark.parametrize("algo", [Algorithm.FLAT, Algorithm.RING,
                                   Algorithm.PALLAS])
 def test_gather_compressed_algorithms(accl, rng, algo):
-    if algo is Algorithm.PALLAS:
-        skip_unless_interpret_rdma()
     count = 19
     send = accl.create_buffer(count, dataType.float32)
     recv = accl.create_buffer(count * WORLD, dataType.float32)
@@ -100,7 +93,6 @@ def test_scatter_alltoall_compressed_flat(accl, rng):
         np.testing.assert_array_equal(ar.host[k], expect)
 
 
-@requires_interpret_rdma
 def test_scatter_alltoall_compressed_pallas(accl, rng):
     """The segmented relay/rotation kernels through the same compressed
     matrix as the FLAT family (small-int payloads are exact through any
@@ -140,7 +132,6 @@ def test_true_float_compressed_tolerance(accl, rng):
 
 
 @pytest.mark.parametrize("wire", [dataType.bfloat16, dataType.float16])
-@requires_interpret_rdma
 def test_allreduce_compressed_pallas(accl, rng, wire):
     """The Pallas RDMA-over-ICI kernels run the wire lanes IN-KERNEL:
     compress in the send slot, decompress before the fold (per-hop
@@ -157,7 +148,6 @@ def test_allreduce_compressed_pallas(accl, rng, wire):
         np.testing.assert_array_equal(recv.host[r], expect)
 
 
-@requires_interpret_rdma
 def test_rs_ag_compressed_pallas(accl, rng):
     count = 64
     s = accl.create_buffer(count * WORLD, dataType.float32)
@@ -178,7 +168,6 @@ def test_rs_ag_compressed_pallas(accl, rng):
         np.testing.assert_array_equal(rg.host[k], sg.host.reshape(-1))
 
 
-@requires_interpret_rdma
 def test_quantized_int8_wire_pallas(accl, rng):
     """Quantized int8 wire (scaled, decompress-before-arith) through the
     Pallas ring — the TPU-native extension riding the perf core."""

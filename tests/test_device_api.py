@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from accl_tpu.compat import shard_map
+from jax import shard_map
 
 from accl_tpu import Communicator, device_api as dapi, reduceFunction
 from accl_tpu.models import mlp, vadd

@@ -73,3 +73,16 @@ def test_launcher_propagates_failure():
 def test_launcher_rejects_missing_prog():
     res = _run_launcher(["-np", "2"], timeout=60)
     assert res.returncode != 0
+
+
+def test_launcher_refuses_tpu_platform():
+    """Several processes on one host cannot share its chips: the launcher
+    is the CPU rung only and says so before starting anything."""
+    from accl_tpu import launch
+
+    with pytest.raises(ValueError, match="one process at a time"):
+        launch.launch(2, [sys.executable, "-c", "pass"], platform="tpu")
+    res = _run_launcher(["-np", "2", "--platform", "tpu",
+                         sys.executable, "-c", "pass"], timeout=60)
+    assert res.returncode != 0
+    assert "refused" in res.stderr

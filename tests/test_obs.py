@@ -491,7 +491,7 @@ def test_fallback_counter_counts_every_decline_site_once(monkeypatch):
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from accl_tpu.compat import shard_map
+    from jax import shard_map
     from accl_tpu.ops import collective_alltoall as ca
     from accl_tpu.ops import collective_matmul as cm
 

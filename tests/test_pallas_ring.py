@@ -7,10 +7,8 @@ import pytest
 
 from accl_tpu import Algorithm, dataType, reduceFunction
 from accl_tpu.parallel import pallas_ring
-from conftest import requires_interpret_rdma
 
 # the whole module simulates cross-device RDMA in interpret mode
-pytestmark = requires_interpret_rdma
 
 WORLD = 8
 

@@ -10,9 +10,8 @@ Ladder rungs covered here:
   GPipe oracle vs a float64 host reference — at worlds {2, 4}, plain
   and interleaved, plus the composed transformer step on pp x dp and
   pp x tp meshes; relay VJP parity; fallback/commit-honesty counting;
-* **interpret**: the relay kernel under the race detector
-  (``requires_interpret_rdma`` — skipped where this jax has no TPU
-  interpreter, like every chunked-kernel suite);
+* **interpret**: the relay kernel under the TPU interpreter's race
+  detector;
 * **AOT v5e:2x4**: the relay kernel and the composed fused step lower
   to Mosaic kernels for real hardware (the *_schedule pin discipline).
 """
@@ -27,7 +26,6 @@ from accl_tpu.communicator import Communicator
 from accl_tpu.models import pipeline as pp
 from accl_tpu.obs import metrics
 from accl_tpu.ops import pipeline_relay as relay
-from conftest import requires_interpret_rdma
 
 
 def _counter(snap_text: str, needle: str) -> bool:
@@ -270,7 +268,7 @@ def test_relay_vjp_parity(accl, rng):
     b = rng.standard_normal((W, n, d)).astype(np.float32)
     sh = comm.sharding(P(pp.AXIS, None, None))
     fg, bg = jax.device_put(f, sh), jax.device_put(b, sh)
-    from accl_tpu.compat import shard_map
+    from jax import shard_map
     from jax import lax
 
     fwd_perm = [(i, (i + 1) % W) for i in range(W)]
@@ -415,7 +413,6 @@ def test_composed_commit_honesty(rng):
 # ---------------------------------------------------------------------------
 
 
-@requires_interpret_rdma
 def test_relay_kernel_race_free(accl, rng, monkeypatch):
     """The double-buffer + credit protocol under the interpret-mode
     race detector (grants == gates; every semaphore drains to zero)."""

@@ -2,7 +2,7 @@
 
 Mirrors ``test_flat_schedule.py``: the multi-axis builders compile
 ahead-of-time against a real v5e 2x4 TPU topology, proving (1) the chip
-coordinates of the real torus auto-detect as the (2, 4) factorization —
+coordinates of the real torus auto-detect as its (y, x) factorization —
 no declaration needed on silicon, (2) the plan resolution picks the
 multi-axis schedule there exactly as on the emulated topology, and
 (3) the whole synthesized schedule lowers as ONE program whose
@@ -31,6 +31,19 @@ def tpu_comm():
     return Communicator(devices)
 
 
+@pytest.fixture(scope="module")
+def coords_grid(tpu_comm):
+    """(rows, cols) of the described chips read straight off their
+    coordinates: cols = the x extent (fastest-varying under snake rank
+    order), rows = the y extent. libtpu's naming of "2x4" changed
+    between releases (x=4 once, x=2 under libtpu 0.0.34), so the
+    expectation comes from the coordinates, not from the name."""
+    xs = {d.coords[0] for d in tpu_comm.devices}
+    ys = {d.coords[1] for d in tpu_comm.devices}
+    assert len(xs) * len(ys) == WORLD and min(len(xs), len(ys)) >= 2
+    return len(ys), len(xs)
+
+
 def _compile_text(fn, comm, *shapes):
     sh = comm.sharding()
     args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=sh)
@@ -38,16 +51,16 @@ def _compile_text(fn, comm, *shapes):
     return fn.lower(*args).compile().as_text()
 
 
-def test_v5e_coords_detect_torus(tpu_comm):
+def test_v5e_coords_detect_torus(tpu_comm, coords_grid):
     """The real 2x4 slice's chip coords ARE the torus declaration: AUTO
     synthesizes multi-axis schedules on silicon with a default config."""
     cfg = ACCLConfig(transport=TransportBackend.ICI)
-    assert synth.torus_shape(tpu_comm, cfg) == (ROWS, COLS)
+    assert synth.torus_shape(tpu_comm, cfg) == coords_grid
     topo = synth.topology_of(tpu_comm, cfg)
-    assert topo.axes == (ROWS, COLS) and topo.multi_axis
+    assert topo.axes == coords_grid and topo.multi_axis
 
 
-def test_v5e_resolution_selects_multiaxis(tpu_comm):
+def test_v5e_resolution_selects_multiaxis(tpu_comm, coords_grid):
     """Plan pin on the real topology: large-payload allreduce resolves
     to the synthesized multi-axis schedule over the flat ring path —
     the chunk-PIPELINED shape under the default config
@@ -61,7 +74,7 @@ def test_v5e_resolution_selects_multiaxis(tpu_comm):
     plan = synth.resolve(operation.allreduce, 8 << 20, tpu_comm, cfg,
                          legacy)
     assert plan.shape == "pipeline" and plan.source == "cost_model"
-    assert plan.param("shape2d") == (ROWS, COLS)
+    assert plan.param("shape2d") == coords_grid
     assert plan.param("pipeline_chunks") == cfg.sched_pipeline_chunks
     synth.validate_plan(plan)
     seq_cfg = cfg.replace(sched_pipeline_chunks=1)

@@ -11,7 +11,6 @@ import jax.numpy as jnp
 from accl_tpu.communicator import Communicator
 from accl_tpu.models import mlp, zero
 from accl_tpu.ops import collective_matmul as cm
-from conftest import requires_interpret_rdma
 
 WORLD = 8
 
@@ -451,7 +450,7 @@ def test_device_api_fsdp_matmul_traces_kernel(accl, monkeypatch):
     forced (the gather IS the matmul), and its VJP traces the dual
     mmrs + wgrad kernels — the whole FSDP communication pattern."""
     from accl_tpu import device_api as dapi
-    from accl_tpu.compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     monkeypatch.setattr(cm, "_kernels_available", lambda: True)
@@ -475,7 +474,6 @@ def test_device_api_fsdp_matmul_traces_kernel(accl, monkeypatch):
 # interpret-RDMA rung: the fused schedule actually executes
 # ---------------------------------------------------------------------------
 
-@requires_interpret_rdma
 @pytest.mark.parametrize("world", [2, 4, 8])
 def test_fsdp_fused_parity_interpret(accl, rng, world):
     """On rungs whose interpreter simulates remote DMA the fused
@@ -503,7 +501,6 @@ def test_fsdp_fused_parity_interpret(accl, rng, world):
                                rtol=1e-4, atol=1e-5)
 
 
-@requires_interpret_rdma
 def test_fsdp_bf16_wire_tolerance_interpret(accl, rng):
     """bf16 wire staging on the fused legs + the bucketized gradient leg
     stays tolerance-bounded vs the full-precision fused run."""

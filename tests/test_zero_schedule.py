@@ -92,8 +92,10 @@ def test_fsdp_train_step_lowers_multihost(fsdp_mesh):
     parameter collectives."""
     L = 2
     compiled = _compile(fsdp_mesh, L)
-    # >= 12 cmatmul + 2 flash Mosaic kernels per layer
-    assert_aot_lowered(compiled, 14 * L)
+    # >= 12 cmatmul + 2 flash Mosaic kernels per layer, less one: the
+    # first layer's Wqkv gathered-wgrad computes d(input), which the
+    # step never differentiates — jax 0.9 drops that leg before lowering
+    assert_aot_lowered(compiled, 14 * L - 1)
 
 
 def test_fsdp_train_step_wire_lowers_multihost(fsdp_mesh):
