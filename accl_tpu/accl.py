@@ -839,12 +839,7 @@ class ACCL:
         self._queue.push(req)
         if run_async:
             return req
-        # request lifecycle: the wait covers complete + finalize (device
-        # readiness + host-mirror sync) — the tail of enqueue -> launch ->
-        # complete -> finalize; dispatch itself is the caller's span
-        with _trace.span(f"req.{scenario.name}.wait", cat="request",
-                         req=req.id):
-            req.wait(timeout=self.config.timeout)
+        req.wait(timeout=self.config.timeout)
         return None
 
     def _key(self, comm: Communicator, op: operation, *extra):
@@ -1097,19 +1092,22 @@ class ACCL:
     ) -> Optional[Request]:
         """Per-rank device copy (``ACCL::copy``; fw copy ccl_offload_control.c:533-549)."""
         t0 = _metrics.tick()
-        comm = self._comm(comm)
-        self._check_count(srcbuf, count, "copy src")
-        self._check_count(dstbuf, count, "copy dst")
-        x = self._input(srcbuf, count, from_device)
-        key, build = self._spec_copy(comm, count, srcbuf.dtype)
-        with _trace.span("accl.copy", cat="collective", count=count):
-            prog = self._programs.get(key, build)
-            y = prog(x).astype(dstbuf.jnp_dtype)
-            self._store(dstbuf, count, y)
-        _metrics.note_call(operation.copy,
-                           count * constants.dtype_size(srcbuf.dtype),
-                           srcbuf.dtype, key, t0)
-        return self._finish(operation.copy, dstbuf, y, to_device, run_async, comm)
+        with _trace.root("accl.copy", count=count):
+            comm = self._comm(comm)
+            self._check_count(srcbuf, count, "copy src")
+            self._check_count(dstbuf, count, "copy dst")
+            x = self._input(srcbuf, count, from_device)
+            with _trace.span("accl.plan"):
+                key, build = self._spec_copy(comm, count, srcbuf.dtype)
+            with _trace.span("accl.launch"):
+                y = self._programs.run(key, build, x).astype(
+                    dstbuf.jnp_dtype)
+                self._store(dstbuf, count, y)
+            _metrics.note_call(operation.copy,
+                               count * constants.dtype_size(srcbuf.dtype),
+                               srcbuf.dtype, key, t0)
+            return self._finish(operation.copy, dstbuf, y, to_device,
+                                run_async, comm)
 
     def combine(
         self,
@@ -1127,22 +1125,28 @@ class ACCL:
         """Per-rank elementwise reduce of two buffers (``ACCL::combine``;
         fw combine :553-571; reduce_ops plugin)."""
         t0 = _metrics.tick()
-        comm = self._comm(comm)
-        for b, w in ((val1, "combine op0"), (val2, "combine op1"), (result, "combine res")):
-            self._check_count(b, count, w)
-        if val1.dtype != val2.dtype:
-            raise ACCLError(errorCode.ARITH_ERROR, "combine operand dtype mismatch")
-        a = self._input(val1, count, val1_from_device)
-        b = self._input(val2, count, val2_from_device)
-        key, build = self._spec_combine(comm, count, val1.dtype, function)
-        with _trace.span("accl.combine", cat="collective", count=count):
-            prog = self._programs.get(key, build)
-            y = prog(a, b).astype(result.jnp_dtype)
-            self._store(result, count, y)
-        _metrics.note_call(operation.combine,
-                           count * constants.dtype_size(val1.dtype),
-                           val1.dtype, key, t0)
-        return self._finish(operation.combine, result, y, to_device, run_async, comm)
+        with _trace.root("accl.combine", count=count):
+            comm = self._comm(comm)
+            for b, w in ((val1, "combine op0"), (val2, "combine op1"),
+                         (result, "combine res")):
+                self._check_count(b, count, w)
+            if val1.dtype != val2.dtype:
+                raise ACCLError(errorCode.ARITH_ERROR,
+                                "combine operand dtype mismatch")
+            a = self._input(val1, count, val1_from_device)
+            b = self._input(val2, count, val2_from_device)
+            with _trace.span("accl.plan"):
+                key, build = self._spec_combine(comm, count, val1.dtype,
+                                                function)
+            with _trace.span("accl.launch"):
+                y = self._programs.run(key, build, a, b).astype(
+                    result.jnp_dtype)
+                self._store(result, count, y)
+            _metrics.note_call(operation.combine,
+                               count * constants.dtype_size(val1.dtype),
+                               val1.dtype, key, t0)
+            return self._finish(operation.combine, result, y, to_device,
+                                run_async, comm)
 
     # ------------------------------------------------------------------
     # two-sided send / recv + one-sided put
@@ -1496,6 +1500,14 @@ class ACCL:
         one zero-copy post, no rx buffer (:595-612). ``compress_dtype``
         compresses the wire payload only (ETH_COMPRESSED semantics).
         """
+        with _trace.root("accl.send", count=count):
+            return self._send(srcbuf, count, src, dst, tag, from_device,
+                              run_async, comm, compress_dtype)
+
+    def _send(self, srcbuf: BufLike, count: int, src: int, dst: int,
+              tag: int, from_device: bool, run_async: bool,
+              comm: Optional[Communicator],
+              compress_dtype: Optional[dataType]) -> Optional[Request]:
         comm = self._comm(comm)
         arith = self._arith(srcbuf.dtype, compress_dtype)
         if arith is not None and arith.quant_scale is not None:
@@ -1527,7 +1539,8 @@ class ACCL:
             _metrics.inc("accl_sendrecv_protocol_total", labels=_L_RDV)
             _metrics.note_call(operation.send, nbytes, srcbuf.dtype)
             post = SendPost(src=src, dst=dst, tag=tag, data=data, count=count)
-            matcher.post_send(post)
+            with _trace.span("accl.match"):
+                matcher.post_send(post)
             return self._finish(operation.send, None, data, True, run_async, comm)
         _metrics.inc("accl_sendrecv_protocol_total", labels=_L_EAGER)
         _metrics.note_call(operation.send, nbytes, srcbuf.dtype)
@@ -1542,8 +1555,12 @@ class ACCL:
             # resolution
             return self._eager_send_fast(matcher, data, count, src, dst,
                                          tag)
-        return self._eager_send(matcher, data, count, srcbuf.dtype,
-                                src, dst, tag, run_async)
+        with _trace.span("accl.match"):
+            req = self._eager_send(matcher, data, count, srcbuf.dtype,
+                                   src, dst, tag, run_async)
+        if run_async:
+            return req
+        return self._finish(operation.send, None, data, True, False, comm)
 
     def _eager_send_fast(self, matcher, data, count: int, src: int,
                          dst: int, tag: int) -> Optional[Request]:
@@ -1558,28 +1575,30 @@ class ACCL:
         "eager_send"}`` histogram — the ms-scale dispatch bins cannot
         resolve a p99 for ops whose whole budget is tens of µs."""
         t0 = _metrics.tick()
-        cap = matcher.recv_capacity(src, dst, tag)
-        if cap >= 0 and cap < count:
-            raise ACCLError(
-                errorCode.INVALID_BUFFER_SIZE,
-                f"send {src}->{dst} count {count} overflows the pending "
-                f"recv's remaining capacity {cap}")
-        slot = matcher.rx_pool.reserve(
-            src, dst, tag, matcher.outbound_seq(src, dst), count)
-        if slot < 0:
-            raise ACCLError(
-                errorCode.NOT_READY_ERROR,
-                f"eager rx-buffer pool exhausted (0 free, 1 needed); "
-                f"drain pending recvs or raise "
-                f"config.eager_rx_buffer_count")
-        post = SendPost(src=src, dst=dst, tag=tag, data=data,
-                        count=count, rx_slot=slot)
-        try:
-            matcher.post_send(post)
-        except Exception:
-            # rejected before the seqn was consumed — give the slot back
-            matcher.rx_pool.release(slot)
-            raise
+        with _trace.span("accl.match"):
+            cap = matcher.recv_capacity(src, dst, tag)
+            if cap >= 0 and cap < count:
+                raise ACCLError(
+                    errorCode.INVALID_BUFFER_SIZE,
+                    f"send {src}->{dst} count {count} overflows the "
+                    f"pending recv's remaining capacity {cap}")
+            slot = matcher.rx_pool.reserve(
+                src, dst, tag, matcher.outbound_seq(src, dst), count)
+            if slot < 0:
+                raise ACCLError(
+                    errorCode.NOT_READY_ERROR,
+                    f"eager rx-buffer pool exhausted (0 free, 1 needed); "
+                    f"drain pending recvs or raise "
+                    f"config.eager_rx_buffer_count")
+            post = SendPost(src=src, dst=dst, tag=tag, data=data,
+                            count=count, rx_slot=slot)
+            try:
+                matcher.post_send(post)
+            except Exception:
+                # rejected before the seqn was consumed — give the slot
+                # back
+                matcher.rx_pool.release(slot)
+                raise
         _metrics.note_latency_dispatch("eager_send", t0)
         return self._finish(operation.send, None, data, True, False,
                             matcher.comm)
@@ -1651,6 +1670,9 @@ class ACCL:
     def _eager_send(self, matcher, data, count: int, dt: dataType,
                     src: int, dst: int, tag: int,
                     run_async: bool) -> Optional[Request]:
+        """Post an eager message's segments: all of them or none (sync;
+        returns None and the caller waits), or what fits now with the
+        rest parked (async; returns the request)."""
         segs = self._segments(count, dt)
         # validate against any parked recv upfront: a mid-message overflow
         # would otherwise strand a half-posted message with shifted seqns
@@ -1722,8 +1744,7 @@ class ACCL:
                         errorCode.DMA_NOT_OKAY_ERROR,
                         f"eager send {src}->{dst}: pool slot vanished at "
                         f"segment {i}/{len(segs)}")
-            return self._finish(operation.send, None, data, True, False,
-                                matcher.comm)
+            return None
 
         # async: post what fits now, park the rest with current_step
         def abort_undelivered() -> None:
@@ -1795,6 +1816,14 @@ class ACCL:
         recv parks like a rendezvous address announcement and its request
         completes on match — ``current_step`` counts delivered segments.
         """
+        with _trace.root("accl.recv", count=count):
+            return self._recv(dstbuf, count, src, dst, tag, to_device,
+                              run_async, comm, compress_dtype)
+
+    def _recv(self, dstbuf: BufLike, count: int, src: int, dst: int,
+              tag: int, to_device: bool, run_async: bool,
+              comm: Optional[Communicator],
+              compress_dtype: Optional[dataType]) -> Optional[Request]:
         comm = self._comm(comm)
         arith = self._arith(dstbuf.dtype, compress_dtype)  # validate the pair
         if arith is not None and arith.quant_scale is not None:
@@ -1834,14 +1863,15 @@ class ACCL:
             n_delivered[0] += 1
             last_eom[0] = spost.eom
             off, seg_off[0] = seg_off[0], seg_off[0] + spost.count
-            prog = self._programs.get(
-                self._key(comm, operation.recv, "move_at",
-                          spost.src, spost.dst),
-                lambda: primitives.build_move_at(comm, spost.src, spost.dst),
-            )
-            dest = self._input(dstbuf, count, True)
-            moved = prog(spost.data, dest, off)
-            self._store(dstbuf, count, moved)
+            with _trace.span("accl.launch"):
+                dest = self._input(dstbuf, count, True)
+                moved = self._programs.run(
+                    self._key(comm, operation.recv, "move_at",
+                              spost.src, spost.dst),
+                    lambda: primitives.build_move_at(comm, spost.src,
+                                                     spost.dst),
+                    spost.data, dest, off)
+                self._store(dstbuf, count, moved)
             if pending_req:
                 pending_req[0].current_step = n_delivered[0]
             if seg_off[0] == count:
@@ -1859,17 +1889,18 @@ class ACCL:
                         deliver=deliver)
 
         if not run_async:
-            done = matcher.post_recv(post)
-            # a partially-filled recv resumes as parked senders free up:
-            # each consumed segment releases a pool slot, the pump lets the
-            # blocked sender post the next segment into this parked recv
-            # (cooperative eager pipeline, fw :628-649)
-            while not done:
-                before = post.remaining
-                self._pump()
-                done = post.remaining == 0
-                if not done and post.remaining == before:
-                    break  # no progress possible
+            with _trace.span("accl.match"):
+                done = matcher.post_recv(post)
+                # a partially-filled recv resumes as parked senders free
+                # up: each consumed segment releases a pool slot, the pump
+                # lets the blocked sender post the next segment into this
+                # parked recv (cooperative eager pipeline, fw :628-649)
+                while not done:
+                    before = post.remaining
+                    self._pump()
+                    done = post.remaining == 0
+                    if not done and post.remaining == before:
+                        break  # no progress possible
             if not done:
                 if seg_off[0] > 0:
                     # segments were consumed — keep the recv parked so the
@@ -1909,7 +1940,8 @@ class ACCL:
         pending_req.append(req)
         try:
             self._queue.push(req)
-            matcher.post_recv(post)
+            with _trace.span("accl.match"):
+                matcher.post_recv(post)
         except Exception as e:
             req.cancel(error=e)
             raise
@@ -1931,22 +1963,24 @@ class ACCL:
         ``dstbuf`` with no matching recv (``ACCL::stream_put`` analog — the
         one-sided primitive, accl.hpp stream_put)."""
         t0 = _metrics.tick()
-        comm = self._comm(comm)
-        self._check_count(srcbuf, count, "put src")
-        self._check_count(dstbuf, count, "put dst")
-        x = self._input(srcbuf, count, from_device)
-        dest = self._input(dstbuf, count, True)
-        with _trace.span("accl.put", cat="collective", count=count):
-            prog = self._programs.get(
-                self._key(comm, operation.put, count, dstbuf.dtype, src, dst),
-                lambda: primitives.build_move(comm, src, dst),
-            )
-            moved = prog(x.astype(dest.dtype), dest)
-            self._store(dstbuf, count, moved)
-        _metrics.note_call(operation.put,
-                           count * constants.dtype_size(srcbuf.dtype),
-                           srcbuf.dtype, None, t0)
-        return self._finish(operation.put, dstbuf, moved, to_device, run_async, comm)
+        with _trace.root("accl.put", count=count):
+            comm = self._comm(comm)
+            self._check_count(srcbuf, count, "put src")
+            self._check_count(dstbuf, count, "put dst")
+            x = self._input(srcbuf, count, from_device)
+            dest = self._input(dstbuf, count, True)
+            with _trace.span("accl.launch"):
+                moved = self._programs.run(
+                    self._key(comm, operation.put, count, dstbuf.dtype, src,
+                              dst),
+                    lambda: primitives.build_move(comm, src, dst),
+                    x.astype(dest.dtype), dest)
+                self._store(dstbuf, count, moved)
+            _metrics.note_call(operation.put,
+                               count * constants.dtype_size(srcbuf.dtype),
+                               srcbuf.dtype, None, t0)
+            return self._finish(operation.put, dstbuf, moved, to_device,
+                                run_async, comm)
 
     # ------------------------------------------------------------------
     # collectives
@@ -1966,19 +2000,21 @@ class ACCL:
     ) -> Optional[Request]:
         """``ACCL::bcast`` (accl.cpp; fw :798-990)."""
         t0 = _metrics.tick()
-        comm = self._comm(comm)
-        self._check_count(buf, count, "bcast")
-        x = self._input(buf, count, from_device)
-        key, build = self._spec_bcast(comm, count, buf.dtype, root,
-                                      compress_dtype, algorithm)
-        with _trace.span("accl.bcast", cat="collective", count=count):
-            prog = self._programs.get(key, build)
-            y = prog(x)
-            self._store(buf, count, y)
-        _metrics.note_call(operation.bcast,
-                           count * constants.dtype_size(buf.dtype),
-                           buf.dtype, key, t0)
-        return self._finish(operation.bcast, buf, y, to_device, run_async, comm)
+        with _trace.root("accl.bcast", count=count):
+            comm = self._comm(comm)
+            self._check_count(buf, count, "bcast")
+            x = self._input(buf, count, from_device)
+            with _trace.span("accl.plan"):
+                key, build = self._spec_bcast(comm, count, buf.dtype, root,
+                                              compress_dtype, algorithm)
+            with _trace.span("accl.launch"):
+                y = self._programs.run(key, build, x)
+                self._store(buf, count, y)
+            _metrics.note_call(operation.bcast,
+                               count * constants.dtype_size(buf.dtype),
+                               buf.dtype, key, t0)
+            return self._finish(operation.bcast, buf, y, to_device,
+                                run_async, comm)
 
     def scatter(
         self,
@@ -1996,21 +2032,26 @@ class ACCL:
         """``ACCL::scatter``: root's ``count*world`` buffer chunked over ranks
         (fw :994-1125)."""
         t0 = _metrics.tick()
-        comm = self._comm(comm)
-        world = comm.world_size
-        self._check_count(sendbuf, count * world, "scatter send")
-        self._check_count(recvbuf, count, "scatter recv")
-        x = self._input(sendbuf, count * world, from_device)
-        key, build = self._spec_scatter(comm, count, sendbuf.dtype, root,
-                                        compress_dtype, algorithm)
-        with _trace.span("accl.scatter", cat="collective", count=count):
-            prog = self._programs.get(key, build)
-            y = prog(x).astype(recvbuf.jnp_dtype)
-            self._store(recvbuf, count, y)
-        _metrics.note_call(operation.scatter,
-                           count * world * constants.dtype_size(sendbuf.dtype),
-                           sendbuf.dtype, key, t0)
-        return self._finish(operation.scatter, recvbuf, y, to_device, run_async, comm)
+        with _trace.root("accl.scatter", count=count):
+            comm = self._comm(comm)
+            world = comm.world_size
+            self._check_count(sendbuf, count * world, "scatter send")
+            self._check_count(recvbuf, count, "scatter recv")
+            x = self._input(sendbuf, count * world, from_device)
+            with _trace.span("accl.plan"):
+                key, build = self._spec_scatter(comm, count, sendbuf.dtype,
+                                                root, compress_dtype,
+                                                algorithm)
+            with _trace.span("accl.launch"):
+                y = self._programs.run(key, build, x).astype(
+                    recvbuf.jnp_dtype)
+                self._store(recvbuf, count, y)
+            _metrics.note_call(
+                operation.scatter,
+                count * world * constants.dtype_size(sendbuf.dtype),
+                sendbuf.dtype, key, t0)
+            return self._finish(operation.scatter, recvbuf, y, to_device,
+                                run_async, comm)
 
     def gather(
         self,
@@ -2027,22 +2068,25 @@ class ACCL:
     ) -> Optional[Request]:
         """``ACCL::gather``: concat all sends at root (fw :1130-1296)."""
         t0 = _metrics.tick()
-        comm = self._comm(comm)
-        world = comm.world_size
-        self._check_count(sendbuf, count, "gather send")
-        self._check_count(recvbuf, count * world, "gather recv")
-        x = self._input(sendbuf, count, from_device)
-        r = self._input(recvbuf, count * world, True)
-        key, build = self._spec_gather(comm, count, sendbuf.dtype, root,
-                                       compress_dtype, algorithm)
-        with _trace.span("accl.gather", cat="collective", count=count):
-            prog = self._programs.get(key, build)
-            y = prog(x, r)
-            self._store(recvbuf, count * world, y)
-        _metrics.note_call(operation.gather,
-                           count * constants.dtype_size(sendbuf.dtype),
-                           sendbuf.dtype, key, t0)
-        return self._finish(operation.gather, recvbuf, y, to_device, run_async, comm)
+        with _trace.root("accl.gather", count=count):
+            comm = self._comm(comm)
+            world = comm.world_size
+            self._check_count(sendbuf, count, "gather send")
+            self._check_count(recvbuf, count * world, "gather recv")
+            x = self._input(sendbuf, count, from_device)
+            r = self._input(recvbuf, count * world, True)
+            with _trace.span("accl.plan"):
+                key, build = self._spec_gather(comm, count, sendbuf.dtype,
+                                               root, compress_dtype,
+                                               algorithm)
+            with _trace.span("accl.launch"):
+                y = self._programs.run(key, build, x, r)
+                self._store(recvbuf, count * world, y)
+            _metrics.note_call(operation.gather,
+                               count * constants.dtype_size(sendbuf.dtype),
+                               sendbuf.dtype, key, t0)
+            return self._finish(operation.gather, recvbuf, y, to_device,
+                                run_async, comm)
 
     def allgather(
         self,
@@ -2058,23 +2102,24 @@ class ACCL:
     ) -> Optional[Request]:
         """``ACCL::allgather`` (fw :1299-1505)."""
         t0 = _metrics.tick()
-        comm = self._comm(comm)
-        world = comm.world_size
-        self._check_count(sendbuf, count, "allgather send")
-        self._check_count(recvbuf, count * world, "allgather recv")
-        x = self._input(sendbuf, count, from_device)
-        key, build = self._spec_allgather(comm, count, sendbuf.dtype,
-                                          compress_dtype, algorithm)
-        with _trace.span("accl.allgather", cat="collective", count=count):
-            prog = self._programs.get(key, build)
-            y = prog(x).astype(recvbuf.jnp_dtype)
-            self._store(recvbuf, count * world, y)
-        nbytes = count * constants.dtype_size(sendbuf.dtype)
-        _metrics.note_call(operation.allgather, nbytes, sendbuf.dtype,
-                           key, t0)
-        if nbytes < self.config.latency_tier_threshold:
-            _metrics.note_latency_dispatch("collective", t0)
-        return self._finish(operation.allgather, recvbuf, y, to_device, run_async, comm)
+        with _trace.root("accl.allgather", count=count):
+            comm = self._comm(comm)
+            world = comm.world_size
+            self._check_count(sendbuf, count, "allgather send")
+            self._check_count(recvbuf, count * world, "allgather recv")
+            x = self._input(sendbuf, count, from_device)
+            with _trace.span("accl.plan"):
+                key, build = self._spec_allgather(comm, count, sendbuf.dtype,
+                                                  compress_dtype, algorithm)
+            with _trace.span("accl.launch"):
+                y = self._programs.run(key, build, x).astype(
+                    recvbuf.jnp_dtype)
+                self._store(recvbuf, count * world, y)
+            _metrics.note_call(operation.allgather,
+                               count * constants.dtype_size(sendbuf.dtype),
+                               sendbuf.dtype, key, t0)
+            return self._finish(operation.allgather, recvbuf, y, to_device,
+                                run_async, comm)
 
     def reduce(
         self,
@@ -2092,21 +2137,24 @@ class ACCL:
     ) -> Optional[Request]:
         """``ACCL::reduce`` (fw :1509-1744)."""
         t0 = _metrics.tick()
-        comm = self._comm(comm)
-        self._check_count(sendbuf, count, "reduce send")
-        self._check_count(recvbuf, count, "reduce recv")
-        x = self._input(sendbuf, count, from_device)
-        r = self._input(recvbuf, count, True)
-        key, build = self._spec_reduce(comm, count, sendbuf.dtype, root,
-                                       function, compress_dtype, algorithm)
-        with _trace.span("accl.reduce", cat="collective", count=count):
-            prog = self._programs.get(key, build)
-            y = prog(x, r)
-            self._store(recvbuf, count, y)
-        _metrics.note_call(operation.reduce,
-                           count * constants.dtype_size(sendbuf.dtype),
-                           sendbuf.dtype, key, t0)
-        return self._finish(operation.reduce, recvbuf, y, to_device, run_async, comm)
+        with _trace.root("accl.reduce", count=count):
+            comm = self._comm(comm)
+            self._check_count(sendbuf, count, "reduce send")
+            self._check_count(recvbuf, count, "reduce recv")
+            x = self._input(sendbuf, count, from_device)
+            r = self._input(recvbuf, count, True)
+            with _trace.span("accl.plan"):
+                key, build = self._spec_reduce(comm, count, sendbuf.dtype,
+                                               root, function, compress_dtype,
+                                               algorithm)
+            with _trace.span("accl.launch"):
+                y = self._programs.run(key, build, x, r)
+                self._store(recvbuf, count, y)
+            _metrics.note_call(operation.reduce,
+                               count * constants.dtype_size(sendbuf.dtype),
+                               sendbuf.dtype, key, t0)
+            return self._finish(operation.reduce, recvbuf, y, to_device,
+                                run_async, comm)
 
     def allreduce(
         self,
@@ -2123,25 +2171,24 @@ class ACCL:
     ) -> Optional[Request]:
         """``ACCL::allreduce`` (accl.cpp:796-842; fw :1855-2075) — the hot path."""
         t0 = _metrics.tick()
-        comm = self._comm(comm)
-        self._check_count(sendbuf, count, "allreduce send")
-        self._check_count(recvbuf, count, "allreduce recv")
-        x = self._input(sendbuf, count, from_device)
-        key, build = self._spec_allreduce(comm, count, sendbuf.dtype,
-                                          function, compress_dtype, algorithm)
-        with _trace.span("accl.allreduce", cat="collective", count=count):
-            prog = self._programs.get(key, build)
-            y = prog(x).astype(recvbuf.jnp_dtype)
-            self._store(recvbuf, count, y)
-        nbytes = count * constants.dtype_size(sendbuf.dtype)
-        _metrics.note_call(operation.allreduce, nbytes, sendbuf.dtype,
-                           key, t0)
-        if nbytes < self.config.latency_tier_threshold:
-            # the latency tier's own dispatch instrument: µs-resolution
-            # buckets (the ms-scale accl_dispatch_seconds bins put every
-            # sub-threshold op in one bucket — no usable p99)
-            _metrics.note_latency_dispatch("collective", t0)
-        return self._finish(operation.allreduce, recvbuf, y, to_device, run_async, comm)
+        with _trace.root("accl.allreduce", count=count):
+            comm = self._comm(comm)
+            self._check_count(sendbuf, count, "allreduce send")
+            self._check_count(recvbuf, count, "allreduce recv")
+            x = self._input(sendbuf, count, from_device)
+            with _trace.span("accl.plan"):
+                key, build = self._spec_allreduce(comm, count, sendbuf.dtype,
+                                                  function, compress_dtype,
+                                                  algorithm)
+            with _trace.span("accl.launch"):
+                y = self._programs.run(key, build, x).astype(
+                    recvbuf.jnp_dtype)
+                self._store(recvbuf, count, y)
+            _metrics.note_call(operation.allreduce,
+                               count * constants.dtype_size(sendbuf.dtype),
+                               sendbuf.dtype, key, t0)
+            return self._finish(operation.allreduce, recvbuf, y, to_device,
+                                run_async, comm)
 
     def reduce_scatter(
         self,
@@ -2159,25 +2206,26 @@ class ACCL:
         """``ACCL::reduce_scatter``: ``count*world`` in, ``count`` out per rank
         (fw :1748-1852)."""
         t0 = _metrics.tick()
-        comm = self._comm(comm)
-        world = comm.world_size
-        self._check_count(sendbuf, count * world, "reduce_scatter send")
-        self._check_count(recvbuf, count, "reduce_scatter recv")
-        x = self._input(sendbuf, count * world, from_device)
-        key, build = self._spec_reduce_scatter(comm, count, sendbuf.dtype,
-                                               function, compress_dtype,
-                                               algorithm)
-        with _trace.span("accl.reduce_scatter", cat="collective",
-                         count=count):
-            prog = self._programs.get(key, build)
-            y = prog(x).astype(recvbuf.jnp_dtype)
-            self._store(recvbuf, count, y)
-        nbytes = count * world * constants.dtype_size(sendbuf.dtype)
-        _metrics.note_call(operation.reduce_scatter, nbytes,
-                           sendbuf.dtype, key, t0)
-        if nbytes < self.config.latency_tier_threshold:
-            _metrics.note_latency_dispatch("collective", t0)
-        return self._finish(operation.reduce_scatter, recvbuf, y, to_device, run_async, comm)
+        with _trace.root("accl.reduce_scatter", count=count):
+            comm = self._comm(comm)
+            world = comm.world_size
+            self._check_count(sendbuf, count * world, "reduce_scatter send")
+            self._check_count(recvbuf, count, "reduce_scatter recv")
+            x = self._input(sendbuf, count * world, from_device)
+            with _trace.span("accl.plan"):
+                key, build = self._spec_reduce_scatter(
+                    comm, count, sendbuf.dtype, function, compress_dtype,
+                    algorithm)
+            with _trace.span("accl.launch"):
+                y = self._programs.run(key, build, x).astype(
+                    recvbuf.jnp_dtype)
+                self._store(recvbuf, count, y)
+            _metrics.note_call(
+                operation.reduce_scatter,
+                count * world * constants.dtype_size(sendbuf.dtype),
+                sendbuf.dtype, key, t0)
+            return self._finish(operation.reduce_scatter, recvbuf, y,
+                                to_device, run_async, comm)
 
     def alltoall(
         self,
@@ -2193,21 +2241,25 @@ class ACCL:
     ) -> Optional[Request]:
         """``ACCL::alltoall`` (fw :2123-2218)."""
         t0 = _metrics.tick()
-        comm = self._comm(comm)
-        world = comm.world_size
-        self._check_count(sendbuf, count * world, "alltoall send")
-        self._check_count(recvbuf, count * world, "alltoall recv")
-        x = self._input(sendbuf, count * world, from_device)
-        key, build = self._spec_alltoall(comm, count, sendbuf.dtype,
-                                         compress_dtype, algorithm)
-        with _trace.span("accl.alltoall", cat="collective", count=count):
-            prog = self._programs.get(key, build)
-            y = prog(x).astype(recvbuf.jnp_dtype)
-            self._store(recvbuf, count * world, y)
-        _metrics.note_call(operation.alltoall,
-                           count * world * constants.dtype_size(sendbuf.dtype),
-                           sendbuf.dtype, key, t0)
-        return self._finish(operation.alltoall, recvbuf, y, to_device, run_async, comm)
+        with _trace.root("accl.alltoall", count=count):
+            comm = self._comm(comm)
+            world = comm.world_size
+            self._check_count(sendbuf, count * world, "alltoall send")
+            self._check_count(recvbuf, count * world, "alltoall recv")
+            x = self._input(sendbuf, count * world, from_device)
+            with _trace.span("accl.plan"):
+                key, build = self._spec_alltoall(comm, count, sendbuf.dtype,
+                                                 compress_dtype, algorithm)
+            with _trace.span("accl.launch"):
+                y = self._programs.run(key, build, x).astype(
+                    recvbuf.jnp_dtype)
+                self._store(recvbuf, count * world, y)
+            _metrics.note_call(
+                operation.alltoall,
+                count * world * constants.dtype_size(sendbuf.dtype),
+                sendbuf.dtype, key, t0)
+            return self._finish(operation.alltoall, recvbuf, y, to_device,
+                                run_async, comm)
 
     def barrier(self, comm: Optional[Communicator] = None) -> None:
         """``ACCL::barrier`` (fw :2078-2120): flush outstanding work, then a
@@ -2217,40 +2269,41 @@ class ACCL:
         zero-byte notification gather/scatter analog) on top of the
         device-level psum, which every controller enters SPMD."""
         t0 = _metrics.tick()
-        comm = self._comm(comm)
-        # flush only THIS communicator's traffic — a sub-communicator
-        # barrier must not block on unrelated communicators (reference
-        # barrier flushes per-communicator seqn state, fw :2081-2090)
-        self._queue.drain(timeout=self.config.timeout, comm=comm)
-        prog = self._programs.get(
-            self._key(comm, operation.barrier),
-            lambda: primitives.build_barrier(comm),
-        )
-        if comm.is_multiprocess:
-            # host-level barrier FIRST, scoped to this communicator's
-            # processes and driving the mover while it waits: a peer may be
-            # blocked inside a pair move this process must co-execute
-            # before it can enter the device collective below. Scoping
-            # fixes the round-2 fabric's all-process over-synchronization
-            # (a 2-rank sub-comm barrier no longer blocks the whole job).
-            procs = sorted({d.process_index for d in comm.devices})
-            self._fabric.barrier(name=self._comm_tag(comm),
-                                 process_ids=procs, pump=self._pump)
-            shards = [
-                jax.device_put(np.ones((1,), np.int32), comm.device(r))
-                for r in comm.local_ranks
-            ]
-            token = jax.make_array_from_single_device_arrays(
-                (comm.world_size,), comm.sharding(), shards)
-        else:
-            token = jax.device_put(
-                np.ones((comm.world_size,), dtype=np.int32), comm.sharding()
-            )
-        with _trace.span("accl.barrier", cat="collective"):
-            jax.block_until_ready(prog(token))
-        # a barrier moves no payload; its "dispatch" histogram entry is
-        # the whole synchronization (drain + host barrier + device psum)
-        _metrics.note_call(operation.barrier, 0, dataType.int32, None, t0)
+        with _trace.root("accl.barrier"):
+            comm = self._comm(comm)
+            # flush only THIS communicator's traffic — a sub-communicator
+            # barrier must not block on unrelated communicators (reference
+            # barrier flushes per-communicator seqn state, fw :2081-2090)
+            self._queue.drain(timeout=self.config.timeout, comm=comm)
+            if comm.is_multiprocess:
+                # host-level barrier FIRST, scoped to this communicator's
+                # processes and driving the mover while it waits: a peer
+                # may be blocked inside a pair move this process must
+                # co-execute before it can enter the device collective
+                # below. Scoping fixes the round-2 fabric's all-process
+                # over-synchronization (a 2-rank sub-comm barrier no
+                # longer blocks the whole job).
+                procs = sorted({d.process_index for d in comm.devices})
+                self._fabric.barrier(name=self._comm_tag(comm),
+                                     process_ids=procs, pump=self._pump)
+                shards = [
+                    jax.device_put(np.ones((1,), np.int32), comm.device(r))
+                    for r in comm.local_ranks
+                ]
+                token = jax.make_array_from_single_device_arrays(
+                    (comm.world_size,), comm.sharding(), shards)
+            else:
+                token = jax.device_put(
+                    np.ones((comm.world_size,), dtype=np.int32),
+                    comm.sharding())
+            with _trace.span("accl.launch"):
+                jax.block_until_ready(self._programs.run(
+                    self._key(comm, operation.barrier),
+                    lambda: primitives.build_barrier(comm), token))
+            # a barrier moves no payload; its "dispatch" histogram entry is
+            # the whole synchronization (drain + host barrier + device psum)
+            _metrics.note_call(operation.barrier, 0, dataType.int32, None,
+                               t0)
 
     @staticmethod
     def _comm_tag(comm: Communicator) -> str:

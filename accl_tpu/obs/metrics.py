@@ -16,7 +16,8 @@ name                                           kind       labels
 =============================================  =========  =================
 ``accl_calls_total``                           counter    op, algorithm, dtype, bucket
 ``accl_bytes_total``                           counter    op, algorithm, dtype, bucket
-``accl_dispatch_seconds``                      histogram  op
+``accl_dispatch_seconds``                      histogram  op (µs-resolution buckets)
+``accl_span_self_seconds``                     sum+count  span (self time of each obs.trace span, recorded while tracing is on)
 ``accl_sendrecv_protocol_total``               counter    protocol (eager | rendezvous | eager_cross | rendezvous_cross)
 ``accl_requests_total``                        counter    op, status
 ``accl_request_duration_seconds``              histogram  op
@@ -38,7 +39,7 @@ name                                           kind       labels
 ``accl_dcn_wire_bytes_total``                  counter    op, dtype, stage (pre | post: two-tier cross-slice leg bytes before/after compression, per dispatch resolution)
 ``accl_program_cache_total``                   counter    event (hit | miss | evict)
 ``accl_program_cache_size``                    gauge      (none)
-``accl_latency_dispatch_seconds``              histogram  path (µs-resolution buckets; eager_send | collective | prefill | decode | verify | handoff | migrate | publish)
+``accl_latency_dispatch_seconds``              histogram  path (µs-resolution buckets; eager_send | prefill | decode | verify | handoff | migrate | publish)
 ``accl_flash_decode_fallback_total``           counter    reason (mode | geometry | vmem_miss)
 ``accl_flash_prefill_fallback_total``          counter    reason (mode | geometry | vmem_miss)
 ``accl_serving_tokens_total``                  counter    phase (prefill | decode | verify), accepted (true | false)
@@ -88,18 +89,24 @@ ENABLED = True
 BUCKETS = (1e-6, 4e-6, 16e-6, 64e-6, 256e-6, 1e-3, 4e-3, 16e-3,
            64e-3, 256e-3, 1.0, 10.0)
 
-#: microsecond-resolution bucket geometry for the latency-tier dispatch
-#: path: the default 4x-spaced buckets put everything from 64 µs to
-#: 256 µs in ONE bin — a sub-threshold op whose whole budget is tens of
-#: µs gets no usable p99 out of that. 2x spacing through the µs decade,
-#: coarse tail for the pathological cases.
+#: microsecond-resolution bucket geometry for the dispatch histograms:
+#: the default 4x-spaced buckets put everything from 64 µs to 256 µs in
+#: ONE bin — a sub-threshold op whose whole budget is tens of µs gets no
+#: usable p99 out of that. 2x spacing through the µs decade, coarse tail
+#: for the pathological cases.
 US_BUCKETS = (1e-6, 2e-6, 4e-6, 8e-6, 16e-6, 32e-6, 64e-6, 128e-6,
               256e-6, 512e-6, 1e-3, 4e-3, 16e-3, 256e-3, 10.0)
+
+#: span self time (:func:`note_span`): one series per span name carrying
+#: only a sum and a count, so a span exit costs no bucket search
+SPAN_SELF = "accl_span_self_seconds"
 
 #: per-metric bucket geometry overrides (by metric NAME, before the
 #: label block); anything absent uses :data:`BUCKETS`
 _BUCKET_OVERRIDES = {
+    "accl_dispatch_seconds": US_BUCKETS,
     "accl_latency_dispatch_seconds": US_BUCKETS,
+    SPAN_SELF: (),
 }
 
 
@@ -183,6 +190,16 @@ class MetricsRegistry:
                     break
             h[-2] += value
             h[-1] += 1
+
+    def add_sum(self, key: str, value: float) -> None:
+        """One sample into a bucketless series (sum and count) whose key
+        the caller built once: the span self-time hot path."""
+        with self._lock:
+            h = self._hists.get(key)
+            if h is None:
+                h = self._hists[key] = [0.0, 0]
+            h[0] += value
+            h[1] += 1
 
     def reset(self) -> None:
         with self._lock:
@@ -372,8 +389,7 @@ def note_latency_dispatch(path: str, t0: float) -> None:
     — the µs-resolution histogram (:data:`US_BUCKETS`; the default
     4x-spaced buckets cannot resolve a p99 for ops whose whole budget is
     tens of µs). ``path`` names the fast path that ran (``eager_send`` —
-    the single-segment eager fast path; ``collective`` — a bandwidth
-    collective below ``latency_tier_threshold``; ``prefill`` /
+    the single-segment eager fast path; ``prefill`` /
     ``decode`` / ``verify`` — the serving tier's step-dispatch phases,
     observed by the ``models.decode`` step wrappers; ``handoff`` /
     ``migrate`` — the router's page transfers; ``publish`` — one full
@@ -384,6 +400,22 @@ def note_latency_dispatch(path: str, t0: float) -> None:
         return
     REGISTRY.observe("accl_latency_dispatch_seconds",
                      time.perf_counter() - t0, (("path", path),))
+
+
+_SPAN_KEYS: Dict[str, str] = {}
+
+
+def note_span(span: str, seconds: float) -> None:
+    """One ``obs.trace`` span's self time into
+    ``accl_span_self_seconds{span}`` (sum and count). The tracer calls
+    this at every span exit while tracing is on; the series key is built
+    once per span name."""
+    if not ENABLED:
+        return
+    key = _SPAN_KEYS.get(span)
+    if key is None:
+        key = _SPAN_KEYS[span] = SPAN_SELF + _label_str((("span", span),))
+    REGISTRY.add_sum(key, seconds)
 
 
 def note_zero_prefetch(event: str, count: int = 1) -> None:

@@ -15,6 +15,10 @@ set — must be *visible*, not a silent recompile tax. Hits, misses,
 evictions and the live size export through ``accl_tpu.obs.metrics``
 (``accl_program_cache_total{event}`` + the ``accl_program_cache_size``
 gauge) beside the ``stats()`` fields that have always been there.
+
+:meth:`ProgramCache.run` is the launch path's lookup-and-call: a miss
+builds the program and makes its first call, where jit traces and
+compiles, inside one ``accl.compile`` span.
 """
 from __future__ import annotations
 
@@ -22,6 +26,7 @@ from collections import OrderedDict
 from typing import Callable, Hashable, Tuple
 
 from ..obs import metrics as _metrics
+from ..obs import trace as _trace
 
 _L_HIT = (("event", "hit"),)
 _L_MISS = (("event", "miss"),)
@@ -42,15 +47,33 @@ class ProgramCache:
     def get(self, key: Hashable, builder: Callable[[], Callable]) -> Callable:
         fn = self._cache.get(key)
         if fn is None:
-            self.misses += 1
-            _metrics.inc("accl_program_cache_total", labels=_L_MISS)
-            fn = builder()
-            self._cache[key] = fn
-            self._evict()
-        else:
-            self.hits += 1
-            _metrics.inc("accl_program_cache_total", labels=_L_HIT)
-            self._cache.move_to_end(key)
+            return self._build(key, builder)
+        return self._hit(key, fn)
+
+    def run(self, key: Hashable, builder: Callable[[], Callable], *args):
+        """Call the program for ``key`` on ``args``, building it on a
+        miss; the build and the first call, which compiles, run inside
+        the ``accl.compile`` span."""
+        fn = self._cache.get(key)
+        if fn is not None:
+            return self._hit(key, fn)(*args)
+        with _trace.span("accl.compile"):
+            return self._build(key, builder)(*args)
+
+    def _hit(self, key: Hashable, fn: Callable) -> Callable:
+        self.hits += 1
+        _metrics.inc("accl_program_cache_total", labels=_L_HIT)
+        self._cache.move_to_end(key)
+        _metrics.set_gauge("accl_program_cache_size", len(self._cache))
+        return fn
+
+    def _build(self, key: Hashable, builder: Callable[[], Callable]
+               ) -> Callable:
+        self.misses += 1
+        _metrics.inc("accl_program_cache_total", labels=_L_MISS)
+        fn = builder()
+        self._cache[key] = fn
+        self._evict()
         _metrics.set_gauge("accl_program_cache_size", len(self._cache))
         return fn
 

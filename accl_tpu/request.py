@@ -24,6 +24,7 @@ import jax
 from . import fault as _fault
 from .constants import ACCLTimeoutError, ACCLError, errorCode
 from .obs import metrics as _metrics
+from .obs import trace as _trace
 
 
 class requestStatus(enum.Enum):
@@ -58,6 +59,9 @@ class Request:
             Request._next_id += 1
             self.id = Request._next_id
         self.scenario = scenario
+        #: the call that issued this request (``obs.trace`` call id; None
+        #: while tracing is off), so a later wait names its call
+        self.call_id = _trace.current_call()
         #: communicator the call was issued on (comm-scoped barrier drains)
         self.comm = comm
         # when the native C++ runtime backs the session, per-request timing
@@ -154,57 +158,69 @@ class Request:
             errorCode.NOT_READY_ERROR, f"{self.scenario} cancelled"))
 
     def wait(self, timeout: Optional[float] = None) -> None:
-        """Block until done (CCLO::wait / BaseRequest::wait analog)."""
+        """Block until done (CCLO::wait / BaseRequest::wait analog).
+        Traced as ``req.<op>.wait``, carrying the call id of the call
+        that issued the request."""
+        if not _trace.ENABLED:
+            return self._wait(timeout)
+        with _trace.span(f"req.{self.scenario}.wait", cat="request",
+                         call=self.call_id, req=self.id):
+            self._wait(timeout)
+
+    def _wait(self, timeout: Optional[float]) -> None:
         if self._external:
-            # wait for fulfill() from a future matching post, pumping the
-            # cooperative scheduler so parked operations can finish. The
-            # poll interval is fault.WAIT_POLICY (the one backoff
-            # implementation): it escalates while pumps make no progress
-            # (idle waits park on the CV instead of spinning) and snaps
-            # back to fast polling the moment anything moves.
-            deadline = ((time.monotonic() + timeout)
-                        if timeout is not None else None)
-            idle = 0
-            while True:
-                if _fault.ENABLED:
-                    # the wait pump is a progress loop too: the chaos
-                    # harness's rank death fires here for requests parked
-                    # on external fulfillment (die/delay only — nothing
-                    # absorbs a transient at this site)
-                    _fault.point("rank.death", kinds=("die", "delay"))
-                if self._progress is not None:
-                    try:
-                        if self._progress():
-                            idle = 0
-                        else:
-                            idle += 1
-                    except ACCLError as e:
-                        if e.code == errorCode.PEER_FAILED:
-                            # bounded-failure verdict from the pump's
-                            # liveness check: retire the request with the
-                            # PEER_FAILED terminal status (counted), then
-                            # surface the error to the caller
-                            self._complete(e)
-                        raise
-                    interval = _fault.WAIT_POLICY.interval(idle)
-                with self._cv:
-                    if self._cv.wait_for(
-                        lambda: self._done or not self._external,
-                        timeout=interval if self._progress else timeout,
-                    ):
-                        break
-                    if self._progress is None:
+            with _trace.span("req.block"):
+                # wait for fulfill() from a future matching post, pumping the
+                # cooperative scheduler so parked operations can finish. The
+                # poll interval is fault.WAIT_POLICY (the one backoff
+                # implementation): it escalates while pumps make no progress
+                # (idle waits park on the CV instead of spinning) and snaps
+                # back to fast polling the moment anything moves.
+                deadline = ((time.monotonic() + timeout)
+                            if timeout is not None else None)
+                idle = 0
+                while True:
+                    if _fault.ENABLED:
+                        # the wait pump is a progress loop too: the chaos
+                        # harness's rank death fires here for requests parked
+                        # on external fulfillment (die/delay only — nothing
+                        # absorbs a transient at this site)
+                        _fault.point("rank.death", kinds=("die", "delay"))
+                    if self._progress is not None:
+                        try:
+                            if self._progress():
+                                idle = 0
+                            else:
+                                idle += 1
+                        except ACCLError as e:
+                            if e.code == errorCode.PEER_FAILED:
+                                # bounded-failure verdict from the pump's
+                                # liveness check: retire the request with the
+                                # PEER_FAILED terminal status (counted), then
+                                # surface the error to the caller
+                                self._complete(e)
+                            raise
+                        interval = _fault.WAIT_POLICY.interval(idle)
+                    with self._cv:
+                        if self._cv.wait_for(
+                            lambda: self._done or not self._external,
+                            timeout=interval if self._progress else timeout,
+                        ):
+                            break
+                        if self._progress is None:
+                            raise ACCLTimeoutError(self.scenario)
+                    if deadline is not None and time.monotonic() > deadline:
                         raise ACCLTimeoutError(self.scenario)
-                if deadline is not None and time.monotonic() > deadline:
-                    raise ACCLTimeoutError(self.scenario)
         if not self._done:
             try:
-                if self._outputs is not None:
-                    jax.block_until_ready(self._outputs)
-                if self._finalizer is not None:
-                    fin, self._finalizer = self._finalizer, None
-                    fin(self)
-                self._complete()
+                with _trace.span("req.block"):
+                    if self._outputs is not None:
+                        jax.block_until_ready(self._outputs)
+                with _trace.span("req.finalize"):
+                    if self._finalizer is not None:
+                        fin, self._finalizer = self._finalizer, None
+                        fin(self)
+                    self._complete()
             except BaseException as e:  # noqa: BLE001 - surfaced via retcode
                 self._complete(e)
         with self._cv:

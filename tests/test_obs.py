@@ -182,12 +182,11 @@ def test_dispatch_histogram_and_algorithm_labels(accl):
     # a 32-byte allreduce rides the latency tier's flat star (round 13)
     assert any('algorithm="flat"' in k and 'op="allreduce"' in k
                for k in d["counters"])
-    # and the sub-threshold dispatch also lands in the µs-resolution
-    # latency-tier histogram
-    [(k, h)] = [(k, h) for k, h in d["histograms"].items()
-                if k.startswith("accl_latency_dispatch_seconds")
-                and 'path="collective"' in k]
-    assert h["count"] == 1 and h["sum"] > 0
+    # the dispatch histogram resolves a sub-threshold dispatch at µs
+    # resolution itself: no second series observes the same interval
+    assert set(h["buckets"]) == {repr(e) for e in metrics.US_BUCKETS}
+    assert not any(k.startswith("accl_latency_dispatch_seconds")
+                   for k in d["histograms"])
 
 
 def test_metrics_disabled_records_nothing(accl):
@@ -203,10 +202,11 @@ def test_metrics_disabled_records_nothing(accl):
 def test_disabled_overhead_budget(accl):
     """Acceptance (ISSUE r8): with telemetry disabled, the ONLY code a
     no-obs build would not run is the guard checks — one tick + note_call
-    + two null spans + two inc()s per collective dispatch. Bound their
-    cost at 5% of one measured allreduce dispatch (a generous multiple of
-    the 1% budget, for CI noise; the obs_overhead bench lane reports the
-    precise figures on silicon)."""
+    + the call's null spans (root, plan, launch, the request's wait,
+    block and finalize) + the call-id read + two inc()s per collective
+    dispatch. Bound their cost at 5% of one measured allreduce dispatch
+    (a generous multiple of the 1% budget, for CI noise; the obs_overhead
+    bench lane reports the precise figures on silicon)."""
     a, b = _mkbuf(accl, 1024), _mkbuf(accl, 1024)
     accl.allreduce(a, b, 1024, reduceFunction.SUM,
                    from_device=True, to_device=True)  # warm the program
@@ -224,14 +224,21 @@ def test_disabled_overhead_budget(accl):
     t0 = time.perf_counter()
     for _ in range(n):
         tick = metrics.tick()
-        with trace.span("accl.allreduce"):
-            pass
-        metrics.inc("accl_algorithm_selected_total")
-        metrics.inc("accl_sendrecv_protocol_total")
-        metrics.note_call(operation.allreduce, 4096, dataType.float32,
-                          None, tick)
-        with trace.span("req.allreduce.wait"):
-            pass
+        with trace.root("accl.allreduce", count=1024):
+            with trace.span("accl.plan"):
+                pass
+            with trace.span("accl.launch"):
+                pass
+            metrics.inc("accl_algorithm_selected_total")
+            metrics.inc("accl_sendrecv_protocol_total")
+            metrics.note_call(operation.allreduce, 4096, dataType.float32,
+                              None, tick)
+            trace.current_call()
+            with trace.span("req.allreduce.wait"):
+                with trace.span("req.block"):
+                    pass
+                with trace.span("req.finalize"):
+                    pass
     per_dispatch_guard = (time.perf_counter() - t0) / n
     metrics.enable()
     assert per_dispatch_guard < 0.05 * t_op, (
@@ -463,9 +470,10 @@ def test_latency_histogram_us_bucket_geometry():
     assert h["buckets"][repr(0.000128)] == 1   # the 100 µs observation
     assert h["count"] == 2
     # a default-bucket histogram is untouched by the override
-    metrics.observe("accl_dispatch_seconds", 5e-6, (("op", "test"),))
+    metrics.observe("accl_request_duration_seconds", 5e-6,
+                    (("op", "test"),))
     hd = metrics.snapshot()["histograms"][
-        'accl_dispatch_seconds{op="test"}']
+        'accl_request_duration_seconds{op="test"}']
     assert len(hd["buckets"]) == len(metrics.BUCKETS)
     # prometheus exposition carries the µs edges cumulatively
     text = metrics.to_prometheus()
