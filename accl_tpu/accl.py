@@ -1030,7 +1030,8 @@ class ACCL:
         algo, plan = algorithms.select_plan(
             operation.allreduce, count * constants.dtype_size(dtype),
             comm, self.config, algorithm, count=count,
-            wire_inert=self._dcn_wire_inert(dtype, arith))
+            wire_inert=self._dcn_wire_inert(dtype, arith),
+            xla_gathers=arith is not None and arith.decompress_before_arith)
         fanin = (self.config.gather_flat_tree_max_fanin
                  if algo == Algorithm.FLAT else 0)
         seg = self.config.segment_size

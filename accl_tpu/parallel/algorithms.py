@@ -156,6 +156,10 @@ def select(
     chunk-PIPELINED (the plan's ``pipeline_chunks`` param; the per-axis
     legs of successive chunks overlap) — on meshes with a declared or
     coordinate-detected torus shape, including declared 3-axis shapes.
+    On ICI such a mesh keeps XLA's own collective unless the ladder's
+    plan strictly beats its price; the multi-axis shapes compete there
+    only for an allreduce whose arith decompresses before arithmetic
+    (``xla_gathers``: XLA's path would all-gather the whole buffer).
     On a host-aligned multi-slice DCN mesh with ``cfg.dcn_wire_dtype``
     set, the synthesizer's per-tier cost model (DCN α/β for cross-slice
     steps, ICI α/β intra-slice) may instead upgrade to the TWO-TIER
@@ -181,6 +185,7 @@ def select_plan(
     requested: Optional[Algorithm] = None,
     count: Optional[int] = None,
     wire_inert: bool = False,
+    xla_gathers: bool = False,
 ):
     """:func:`select` plus the resolved :class:`synth.SchedulePlan` when
     the synthesizer owned the decision (None for explicit requests,
@@ -192,9 +197,12 @@ def select_plan(
     hop, or a payload dtype the codec refuses (ints, bf16/f16): the
     two-tier window stays closed there (the builders stand the
     per-leg codec down, so pricing or counting it would describe an
-    exchange that never runs)."""
+    exchange that never runs). ``xla_gathers`` marks an allreduce whose
+    arith decompresses before arithmetic: XLA's native path then
+    all-gathers the whole wire buffer (``primitives.build_allreduce``),
+    so the ICI window does not offer it (:func:`synth.resolve`)."""
     algo, plan = _select(op, nbytes, comm, cfg, requested, count,
-                         wire_inert)
+                         wire_inert, xla_gathers)
     _metrics.inc("accl_algorithm_selected_total",
                  labels=(("op", op.name), ("algorithm", algo.value)))
     if plan is not None and plan.shape == "twotier":
@@ -212,6 +220,7 @@ def _select(
     requested: Optional[Algorithm] = None,
     count: Optional[int] = None,
     wire_inert: bool = False,
+    xla_gathers: bool = False,
 ):
     algo = requested or cfg.algorithm
     if algo != Algorithm.AUTO:
@@ -242,7 +251,8 @@ def _select(
         # decision to the multi-axis torus decomposition (cached per
         # (op, topology, size-bucket); legacy seeds stay binding)
         plan = synth.resolve(op, nbytes, comm, cfg, legacy, count=count,
-                             wire_inert=wire_inert)
+                             wire_inert=wire_inert,
+                             xla_gathers=xla_gathers)
         return plan.algorithm, plan
     return legacy, None
 

@@ -597,7 +597,9 @@ def _gen_pipeline(op, topo: Topology, N: int, model: CostModel,
     phase hides under the bottleneck phase's wire time, and each extra
     chunk pays one pipeline-fill ``startup_us`` (calibrated on real ICI
     by ``bench.autotune_sched_synth``) — vs the sequential candidate's
-    ``sum(phase costs)``."""
+    ``sum(phase costs)``.  A v5e 2x2 did not bear the overlap out (a
+    4-chunk 1 GiB allreduce took 47.1 ms against 44.0 ms sequential),
+    so :func:`resolve` leaves this candidate out of the ICI window."""
     if not topo.multi_axis or chunks < 2:
         return None
     specs = _multiaxis_phase_specs(op, topo, N)
@@ -1011,7 +1013,8 @@ def _cost_fingerprint(cfg: ACCLConfig) -> tuple:
 
 def resolve(op: operation, nbytes: int, comm, cfg: ACCLConfig,
             legacy: Algorithm, count: Optional[int] = None,
-            wire_inert: bool = False) -> SchedulePlan:
+            wire_inert: bool = False,
+            xla_gathers: bool = False) -> SchedulePlan:
     """Resolve THE schedule plan for one call — the cost-model search,
     memoized per (op, topology, size-bucket, legacy decision, cost
     params).  ``legacy`` is what the scalar-threshold ladder chose; the
@@ -1041,6 +1044,11 @@ def resolve(op: operation, nbytes: int, comm, cfg: ACCLConfig,
       (``cfg.sched_pipeline_chunks``; the pipelined shape wins exactly
       where ``max(phase costs) + (chunks-1)·startup`` undercuts the
       sequential sum) — beats the legacy family's predicted α-β cost.
+      On ICI that window is XLA's own collective against the legacy
+      family instead (source ``cost_model`` whichever wins), save for
+      calls marked ``xla_gathers``: an allreduce whose arith
+      decompresses before arithmetic, which XLA's path runs as an
+      all-gather of the whole buffer.
 
     ``cfg.sched_full_authority`` (default off) short-circuits the seed
     and tier rules: the argmin over the whole candidate family decides
@@ -1077,7 +1085,7 @@ def resolve(op: operation, nbytes: int, comm, cfg: ACCLConfig,
         wire_key = None
     key = (op, topo, _metrics.size_bucket(nbytes), in_tier,
            legacy, seeds, _cost_fingerprint(cfg), wire_key,
-           _session_epoch, _recal_gen)
+           bool(xla_gathers), _session_epoch, _recal_gen)
     global _plan_hits, _plan_misses, _plan_evictions
     with _plan_lock:
         plan = _plan_cache.get(key)
@@ -1181,20 +1189,32 @@ def resolve(op: operation, nbytes: int, comm, cfg: ACCLConfig,
         plan = dataclasses.replace(
             _plan_for_algo(legacy, op, topo, nbytes, cfg), source="override")
     else:
-        # the multi-axis window: sequential decomposition and the
-        # chunk-pipelined variant compete against the legacy family —
-        # strict improvement required, checked in (multiaxis, pipeline)
-        # order, so the pipelined candidate wins exactly where
+        # the multi-axis window — strict improvement over the baseline
+        # required (ties keep the simpler schedule). On ICI the baseline
+        # is XLA's own collective at the live topology's pricing, and
+        # only the legacy family challenges it: on a v5e 2x2 XLA ran
+        # every op and size fastest, the sequential multi-axis shape
+        # moves the same bytes in no fewer hops (at best a tie on
+        # paper), and the pipelined shape's overlap did not show on the
+        # chip (docs/scheduling.md). Elsewhere — and for an allreduce
+        # XLA would run as a whole-buffer gather (``xla_gathers``, which
+        # _gen_xla does not price) — the sequential decomposition and
+        # the chunk-pipelined variant compete against the legacy family,
+        # checked in (multiaxis, pipeline) order, so the pipelined
+        # candidate wins exactly where
         # max(phase costs) + (chunks-1)·startup < sum(phase costs)
-        # (ties keep the simpler schedule)
         legacy_plan = _plan_for_algo(legacy, op, topo, nbytes, cfg)
         model = CostModel.from_config(cfg, topo.transport)
         N = _payload_total(op, nbytes, topo.world)
-        best = legacy_plan
-        for cand in (_gen_multiaxis(op, topo, N, model),
-                     _gen_pipeline(op, topo, N, model,
-                                   cfg.sched_pipeline_chunks,
-                                   cfg.sched_pipeline_startup_us)):
+        if topo.transport == TransportBackend.ICI and not xla_gathers:
+            best, rivals = _gen_xla(op, topo, N, model), (legacy_plan,)
+        else:
+            best = legacy_plan
+            rivals = (_gen_multiaxis(op, topo, N, model),
+                      _gen_pipeline(op, topo, N, model,
+                                    cfg.sched_pipeline_chunks,
+                                    cfg.sched_pipeline_startup_us))
+        for cand in rivals:
             if cand is not None and cand.predicted_us < best.predicted_us:
                 best = cand
         plan = dataclasses.replace(best, source="cost_model")

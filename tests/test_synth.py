@@ -910,6 +910,120 @@ def test_pipeline_cost_formula():
     assert plan.shape == "multiaxis"
 
 
+_MIB = 1 << 20
+_BYTES_OPS = (operation.allreduce, operation.reduce_scatter,
+              operation.allgather)
+
+
+def _nccl_nbytes(op, S, world):
+    """select()'s byte convention for nccl-tests' per-rank S (the
+    allreduce buffer, the reduce-scatter input, the allgather output)."""
+    return S // world if op == operation.allgather else S
+
+
+@pytest.mark.parametrize("S", [16 * _MIB, 128 * _MIB, 1024 * _MIB])
+@pytest.mark.parametrize("op", _BYTES_OPS, ids=lambda o: o.name)
+@pytest.mark.parametrize("shape", [[2, 2], [2, 4]], ids=["2x2", "2x4"])
+def test_ici_multiaxis_window_resolves_xla(shape, op, S):
+    """On an ICI torus XLA's own collective is the window's baseline
+    and only the ladder's plan challenges it, which must strictly beat
+    it — so AUTO keeps XLA, decided by the cost model. Explicit
+    MULTIAXIS still runs."""
+    world = shape[0] * shape[1]
+    comm = _FakeComm([object()] * world)
+    cfg = ACCLConfig(transport=TransportBackend.ICI, sched_mesh_shape=shape)
+    nbytes = _nccl_nbytes(op, S, world)
+    assert algorithms.select(op, nbytes, comm, cfg) == Algorithm.XLA
+    legacy = algorithms._select_legacy(op, nbytes, comm, cfg)
+    plan = synth.resolve(op, nbytes, comm, cfg, legacy)
+    assert plan.shape == "xla" and plan.source == "cost_model"
+    assert plan.algorithm == Algorithm.XLA
+    assert plan.topology.axes == tuple(shape)
+    synth.validate_plan(plan)
+    assert algorithms.select(op, nbytes, comm, cfg,
+                             requested=Algorithm.MULTIAXIS) \
+        == Algorithm.MULTIAXIS
+
+
+def test_ici_window_counts_xla_plan():
+    """The decision is visible in the existing plan counter: shape
+    ``xla``, source ``cost_model``, once per synthesized plan."""
+    comm = _FakeComm([object()] * 4)
+    cfg = ACCLConfig(transport=TransportBackend.ICI, sched_mesh_shape=[2, 2],
+                     sched_alpha_us=1.0 + 3e-9)   # fresh cache keys
+    key = ('accl_sched_plan_total{op="reduce_scatter",shape="xla",'
+           'source="cost_model"}')
+    before = _counter(key)
+    for _ in range(3):
+        algorithms.select(operation.reduce_scatter, 64 * _MIB, comm, cfg)
+    assert _counter(key) == before + 1
+
+
+@pytest.mark.parametrize("axes", [(2, 2), (2, 4), (4, 4), (2, 2, 2)])
+def test_ici_sequential_multiaxis_never_undercuts_xla(axes):
+    """Why the ICI window leaves the sequential multi-axis shape out:
+    it moves XLA's bytes (N·(P-1)/P per sweep) in Σ(sᵢ-1) ≥ ⌈log2 P⌉
+    hops, so under the model it at best ties XLA (up to rounding)."""
+    topo = synth.Topology(axes, TransportBackend.ICI, True)
+    model = synth.CostModel.from_config(
+        ACCLConfig(transport=TransportBackend.ICI), topo.transport)
+    for op in _BYTES_OPS:
+        for S in (16 * _MIB, 128 * _MIB, 1024 * _MIB):
+            N = synth._payload_total(op, S, topo.world)
+            xla = synth._gen_xla(op, topo, N, model)
+            seq = synth._gen_multiaxis(op, topo, N, model)
+            assert seq.predicted_us >= xla.predicted_us * (1 - 1e-12)
+
+
+@pytest.mark.parametrize("wire", [dataType.float16, dataType.bfloat16],
+                         ids=lambda d: d.name)
+@pytest.mark.parametrize("shape", [[2, 2], [2, 4]], ids=["2x2", "2x4"])
+def test_ici_compressed_allreduce_keeps_multiaxis(shape, wire):
+    """An fp32 allreduce with a casting wire decompresses before its
+    arithmetic, so XLA's path would all-gather the whole buffer: the
+    ICI window does not offer XLA there (``xla_gathers``), and the
+    multi-axis candidates compete against the ladder as before — a
+    128 MiB call still resolves the chunk-pipelined MULTIAXIS plan."""
+    from accl_tpu.arithconfig import DEFAULT_ARITH_CONFIG
+    arith = DEFAULT_ARITH_CONFIG[(dataType.float32, wire)]
+    assert arith.decompress_before_arith
+    world = shape[0] * shape[1]
+    comm = _FakeComm([object()] * world)
+    cfg = ACCLConfig(transport=TransportBackend.ICI, sched_mesh_shape=shape)
+    algo, plan = algorithms.select_plan(operation.allreduce, 128 * _MIB,
+                                        comm, cfg, xla_gathers=True)
+    assert algo == Algorithm.MULTIAXIS
+    assert plan.shape == "pipeline" and plan.source == "cost_model"
+    assert plan.param("shape2d") == tuple(shape)
+    synth.validate_plan(plan)
+    algo, plan = algorithms.select_plan(operation.allreduce, 128 * _MIB,
+                                        comm, cfg)
+    assert algo == Algorithm.XLA and plan.shape == "xla"
+
+
+@pytest.mark.parametrize("wire", [dataType.float16, dataType.bfloat16],
+                         ids=lambda d: d.name)
+def test_session_marks_compressed_allreduce_xla_gathers(accl, wire):
+    """The dispatch layer feeds the call's arith into the ICI window: on
+    a declared 2x4 a compressed fp32 allreduce dispatches MULTIAXIS,
+    the same call uncompressed dispatches XLA (spec only, no build)."""
+    saved = accl.config
+    accl.config = saved.replace(transport=TransportBackend.ICI,
+                                sched_mesh_shape=[2, 4])
+    count = 32 * _MIB                   # 128 MiB of f32 per rank
+    comm = accl.global_comm()
+    try:
+        for compress, algo in ((wire, "multiaxis"), (None, "xla")):
+            key = ('accl_algorithm_selected_total{op="allreduce",'
+                   f'algorithm="{algo}"}}')
+            before = _counter(key)
+            accl._spec_allreduce(comm, count, dataType.float32,
+                                 reduceFunction.SUM, compress, None)
+            assert _counter(key) == before + 1
+    finally:
+        accl.config = saved
+
+
 def test_pipeline_chunks_1_resolution_byte_identical(accl):
     """THE equivalence pin: sched_pipeline_chunks=1 resolves EXACTLY
     as the pre-pipelining refactor — sequential multiaxis in the ring

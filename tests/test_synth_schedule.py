@@ -3,8 +3,9 @@
 Mirrors ``test_flat_schedule.py``: the multi-axis builders compile
 ahead-of-time against a real v5e 2x4 TPU topology, proving (1) the chip
 coordinates of the real torus auto-detect as its (y, x) factorization —
-no declaration needed on silicon, (2) the plan resolution picks the
-multi-axis schedule there exactly as on the emulated topology, and
+no declaration needed on silicon, (2) the plan resolution keeps XLA's
+own collective there (ICI's baseline) while explicit MULTIAXIS requests
+plan the multi-axis schedule on the detected grid, and
 (3) the whole synthesized schedule lowers as ONE program whose
 scheduled module runs the per-axis collectives (no flat 8-rank ring in
 sight). Compile-only — skips where libtpu cannot provide topology
@@ -60,27 +61,39 @@ def test_v5e_coords_detect_torus(tpu_comm, coords_grid):
     assert topo.axes == coords_grid and topo.multi_axis
 
 
-def test_v5e_resolution_selects_multiaxis(tpu_comm, coords_grid):
-    """Plan pin on the real topology: large-payload allreduce resolves
-    to the synthesized multi-axis schedule over the flat ring path —
-    the chunk-PIPELINED shape under the default config
-    (sched_pipeline_chunks=4), the sequential one with pipelining
-    off."""
+def test_v5e_resolution_keeps_xla_explicit_multiaxis_plans(tpu_comm,
+                                                           coords_grid):
+    """Plan pin on the real topology: AUTO's large-payload allreduce
+    keeps XLA's own collective on the detected torus (ICI's window
+    baseline, which the ladder's plan does not beat), decided by the
+    cost model; an explicit MULTIAXIS request still selects the
+    multi-axis schedule, whose plans — sequential and pipelined —
+    validate on the detected grid."""
     cfg = ACCLConfig(transport=TransportBackend.ICI)
     got = algorithms.select(operation.allreduce, 8 << 20, tpu_comm, cfg)
-    assert got == Algorithm.MULTIAXIS
+    assert got == Algorithm.XLA
     legacy = algorithms._select_legacy(operation.allreduce, 8 << 20,
                                        tpu_comm, cfg)
     plan = synth.resolve(operation.allreduce, 8 << 20, tpu_comm, cfg,
                          legacy)
-    assert plan.shape == "pipeline" and plan.source == "cost_model"
-    assert plan.param("shape2d") == coords_grid
-    assert plan.param("pipeline_chunks") == cfg.sched_pipeline_chunks
-    synth.validate_plan(plan)
-    seq_cfg = cfg.replace(sched_pipeline_chunks=1)
-    seq = synth.resolve(operation.allreduce, 8 << 20, tpu_comm, seq_cfg,
-                        legacy)
-    assert seq.shape == "multiaxis" and seq.source == "cost_model"
+    assert plan.shape == "xla" and plan.source == "cost_model"
+    assert plan.topology.axes == coords_grid
+    assert algorithms.select(operation.allreduce, 8 << 20, tpu_comm, cfg,
+                             requested=Algorithm.MULTIAXIS) \
+        == Algorithm.MULTIAXIS
+    topo = synth.topology_of(tpu_comm, cfg)
+    seq = synth._plan_for_algo(Algorithm.MULTIAXIS, operation.allreduce,
+                               topo, 8 << 20, cfg)
+    assert seq.shape == "multiaxis" and seq.param("shape2d") == coords_grid
+    synth.validate_plan(seq)
+    pipe = synth._gen_pipeline(
+        operation.allreduce, topo, 8 << 20,
+        synth.CostModel.from_config(cfg, topo.transport),
+        cfg.sched_pipeline_chunks, cfg.sched_pipeline_startup_us)
+    assert pipe.param("shape2d") == coords_grid
+    assert pipe.param("pipeline_chunks") == cfg.sched_pipeline_chunks
+    assert seq.predicted_us > plan.predicted_us
+    synth.validate_plan(pipe)
 
 
 _COLLECTIVE = re.compile(
