@@ -873,11 +873,18 @@ class ACCL:
 
     def _spec_combine(self, comm, count: int, dtype: dataType,
                       function: reduceFunction):
-        use_pallas = self.config.use_pallas and self.config.enable_arith
+        # the implementation is in the key, where note_call reads the
+        # dispatched algorithm: PALLAS for the reduce_ops plugin kernel,
+        # XLA for the fused jnp fallback
+        from .ops import reduce_ops
+        impl = (Algorithm.PALLAS
+                if self.config.use_pallas and self.config.enable_arith
+                and dtype in reduce_ops.PALLAS_DTYPES else Algorithm.XLA)
         return (self._key(comm, operation.combine, count, dtype, function,
-                          use_pallas),
-                lambda: primitives.build_combine(comm, function, dtype,
-                                                 use_pallas=use_pallas))
+                          impl),
+                lambda: primitives.build_combine(
+                    comm, function, dtype,
+                    use_pallas=impl == Algorithm.PALLAS))
 
     def _spec_bcast(self, comm, count: int, dtype: dataType, root: int,
                     compress_dtype, algorithm):

@@ -83,6 +83,25 @@ def _merge_partials(o_c, lse_c, o_s, lse_s):
     return o, m + jnp.log(safe)
 
 
+def _after_step_kernels(kv, lse):
+    """Make the K/V hop wait for this ring step's flash kernels, by a
+    data dependency on their running ``lse`` (a pytree of (n,) arrays).
+
+    For the interpret rung only. There each interpreted Pallas kernel
+    parks a host thread in a barrier across devices, and XLA CPU runs a
+    ``ppermute`` beside it on a thread of the same pool (one thread per
+    device), since nothing orders the two. With two parked threads per
+    device the pool runs dry with one rank short of the permute, and
+    XLA aborts the run at its collective timeout. ``lse[0] * 0`` is an
+    exact zero the compiler cannot fold away (NaN * 0 is not 0), so
+    adding it chains each hop after the step's kernels, and by the
+    transpose each backward hop before the previous step's kernels. On
+    TPU the hop should overlap the step's compute, so it is not used
+    there."""
+    dep = sum(l[0] for l in jax.tree.leaves(lse)) * 0
+    return tuple(x + dep.astype(x.dtype) for x in kv)
+
+
 def build_ring_attention(comm: Communicator, causal: bool = False,
                          scale: Optional[float] = None,
                          use_flash: bool = False) -> Callable:
@@ -114,6 +133,7 @@ def build_ring_attention(comm: Communicator, causal: bool = False,
         # the cond skips the kernel entirely — the reference's
         # masked-block skip at zero FLOPs.
         skip_via_cond = _jax.default_backend() == "tpu"
+        interpreted = _flash._interpret_params() is not None
 
         def body_flash(q, k, v):
             q, k, v = q[0], k[0], v[0]                # (n, d) local blocks
@@ -153,6 +173,8 @@ def build_ring_attention(comm: Communicator, causal: bool = False,
                     else:
                         o_c, lse_c = attend((o_c, lse_c))
                 if s + 1 < world:
+                    if interpreted:
+                        kb, vb = _after_step_kernels((kb, vb), lse_c)
                     kb = lax.ppermute(kb, AXIS, perm)
                     vb = lax.ppermute(vb, AXIS, perm)
             return o_c.astype(q.dtype)[None]
@@ -264,6 +286,7 @@ def build_zigzag_ring_attention(comm: Communicator,
         # the CPU rung, so there both branches run and lse masking picks
         # one; on real TPU the cond skips the dead branch's kernel
         skip_via_cond = _jax.default_backend() == "tpu"
+        interpreted = _flash._interpret_params() is not None
 
         def body_flash(q, k, v):
             q, k, v = q[0], k[0], v[0]
@@ -330,6 +353,8 @@ def build_zigzag_ring_attention(comm: Communicator,
                         oB = jnp.where(take_early, oB, oB2)
                         lB = jnp.where(take_early, lB, lB2)
                 if s + 1 < world:
+                    if interpreted:
+                        kb, vb = _after_step_kernels((kb, vb), (lA, lB))
                     kb = lax.ppermute(kb, AXIS, perm)
                     vb = lax.ppermute(vb, AXIS, perm)
             return jnp.concatenate([oA, oB], 0).astype(q.dtype)[None]

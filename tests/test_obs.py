@@ -189,6 +189,22 @@ def test_dispatch_histogram_and_algorithm_labels(accl):
                    for k in d["histograms"])
 
 
+@pytest.mark.parametrize("dt,impl", [(dataType.float32, "pallas"),
+                                     (dataType.float64, "xla")],
+                         ids=["f32", "f64"])
+def test_combine_line_names_its_implementation(accl, dt, impl):
+    """A combine's algorithm label names what ran: the Pallas reduce_ops
+    plugin for the dtypes it has a lane for, the jnp fallback (``xla``)
+    for the others."""
+    a, b, c = (_mkbuf(accl, dt=dt) for _ in range(3))
+    before = metrics.snapshot()
+    accl.combine(N, reduceFunction.SUM, a, b, c)
+    d = metrics.delta(before)
+    assert [k for k in d["counters"] if k.startswith("accl_calls_total")
+            ] == [f'accl_calls_total{{op="combine",algorithm="{impl}",'
+                  f'dtype="{dt.name}",bucket="<=1KiB"}}']
+
+
 def test_metrics_disabled_records_nothing(accl):
     before = metrics.snapshot()
     metrics.disable()
